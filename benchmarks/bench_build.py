@@ -259,6 +259,8 @@ def run_sharded(out_path: str = OUT_PATH, smoke: bool = False) -> list:
 
 def main():
     import argparse
+    from repro.utils import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny end-to-end run, no perf bars / JSON output")

@@ -660,6 +660,8 @@ def run(out_path: str = OUT_PATH, smoke: bool = False,
 
 def main():
     import argparse
+    from repro.utils import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny end-to-end run, no perf bars / JSON output")

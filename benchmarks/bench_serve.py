@@ -260,6 +260,8 @@ def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
 
 def main():
     import argparse
+    from repro.utils import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny corpus, no floors, no JSON")

@@ -18,7 +18,6 @@ deterministic; the threaded server owns its *own* scheduling on top.
 from __future__ import annotations
 
 import dataclasses
-import math
 import threading
 import time
 from typing import Optional, Sequence
@@ -171,26 +170,15 @@ class Session:
 
         idx = self.index
         idx.search_batch(requests, with_metadata=False)
-        scfgs = [idx._resolve_scfg(r) for r in requests]
-        eng = idx.engine
-        cfg = eng.config
+        queries, sels, scfgs = idx._prepare(requests, None)
         mb = search_mod.MIN_COMPACT_BUCKET
 
         def ladder_pass(cfgs) -> None:
             """Group exactly as the engine will under ``cfgs`` and run
             each group at every power-of-two width the padded driver
             can compile (``mb`` .. next_pow2(group size))."""
-            groups: dict = {}
-            for i, r in enumerate(requests):
-                sel = idx.compile_filter(r.filter)
-                plan = sel.plan(cfg.ql, cfg.cap, cfg.qr)
-                route = eng._route(plan, cfgs[i])
-                eff = 1 << max(5, math.ceil(
-                    math.log2(max(route.effective_l, 1))))
-                eff = min(eff, cfgs[i].max_pool)
-                groups.setdefault((route.mechanism, eff, cfgs[i]),
-                                  []).append(i)
-            for members in groups.values():
+            for g in idx.engine.plan_groups(queries, sels, cfgs):
+                members = g.idxs
                 n = len(members)
                 w = mb
                 top = max(w, search_mod._pow2_at_least(n))

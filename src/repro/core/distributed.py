@@ -61,7 +61,7 @@ from repro.core import pq as pq_mod
 from repro.core import search as search_mod
 from repro.core.records import RecordStore
 from repro.core.selectors import InMemory, QueryFilter
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -423,6 +423,19 @@ def _make_nav_prune(plan: ShardPlan, medoid: int, pell: int, r: int,
     return jax.jit(f)
 
 
+def _make_replicated_prune(plan: ShardPlan, r: int, alpha: float):
+    """``robust_prune_batch`` for the sharded build's overflow rows. Their
+    inputs are replicated over the mesh, and the chip's compiler cannot
+    partition a Pallas kernel on its own, so every device runs the same
+    prune under shard_map."""
+    f = shard_map(
+        lambda d, p, c: graph_mod.robust_prune_batch(d, p, c, r=r,
+                                                     alpha=alpha),
+        mesh=plan.mesh, in_specs=(P(), P(), P()), out_specs=P(),
+        check_vma=False)
+    return jax.jit(f)
+
+
 def build_vamana_sharded(data: np.ndarray, plan: ShardPlan, r: int = 32,
                          ell: int = 64, alpha: float = 1.2,
                          batch: int = 1024, seed: int = 0,
@@ -455,26 +468,30 @@ def build_vamana_sharded(data: np.ndarray, plan: ShardPlan, r: int = 32,
     adj0 = rng.integers(0, n, size=(n, r), dtype=np.int64).astype(np.int32)
     adj0[adj0 == np.arange(n, dtype=np.int32)[:, None]] = medoid
 
-    data_dev = jnp.asarray(data)
-    adj_ext = jnp.concatenate(
-        [jnp.asarray(adj0), jnp.full((1, r), -1, jnp.int32)])
+    # everything but the batch ids is replicated over the mesh once, up
+    # front, instead of re-broadcast by every step that reads it
+    rep = NamedSharding(plan.mesh, P())
+    data_dev = jax.device_put(data, rep)
+    adj_ext = jax.device_put(
+        np.concatenate([adj0, np.full((1, r), -1, np.int32)]), rep)
     batch = min(batch, graph_mod._pow2_pad(n))
     assert batch % plan.n_shards == 0, (
         f"batch={batch} must divide over {plan.n_shards} shards")
     use_pq = codes is not None
     if use_pq:
         assert codebook is not None
-        codes_dev = jnp.asarray(codes)
-        cents_dev = jnp.asarray(codebook.centroids)
+        codes_dev = jax.device_put(codes, rep)
+        cents_dev = jax.device_put(codebook.centroids, rep)
     else:
         # 1-row placeholders keep one body signature (dead under !use_pq)
-        codes_dev = jnp.zeros((1, 1), jnp.uint8)
-        cents_dev = jnp.zeros((1, 1, 1), jnp.float32)
+        codes_dev = jax.device_put(np.zeros((1, 1), np.uint8), rep)
+        cents_dev = jax.device_put(np.zeros((1, 1, 1), np.float32), rep)
 
     for pass_i, alpha_pass in enumerate((1.0, alpha)):
         pell = ell if pass_i else max(16, (2 * ell) // 3)
         nav_prune = _make_nav_prune(plan, medoid, pell, r,
                                     float(alpha_pass), use_pq)
+        prune = _make_replicated_prune(plan, r, float(alpha_pass))
         order = rng.permutation(n)
         for start in range(0, n, batch):
             ids, live = graph_mod._pad_batch(
@@ -491,7 +508,7 @@ def build_vamana_sharded(data: np.ndarray, plan: ShardPlan, r: int = 32,
                 adj_ext, jnp.asarray(ids), jnp.asarray(live), rows)
             adj_ext = graph_mod._drain_overflow(
                 data_dev, adj_ext, st, ss, overflow, ids.shape[0], r,
-                float(alpha_pass))
+                float(alpha_pass), prune=prune)
             if stage_times is not None:
                 adj_ext.block_until_ready()
                 stage_times["scatter_s"] = (

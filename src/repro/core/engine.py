@@ -10,6 +10,8 @@ Baseline policies (paper §5.1 compared systems) are selectable:
   * ``basefilter``  — PipeANN-BaseFilter: strict pre-filtering when
                       selectivity < 1%, otherwise post-filtering.
   * ``strict_in``   — Filtered-DiskANN-like strict in-filtering.
+  * ``spec_in``     — speculative in-filtering, pinned (the router's
+                      ``in`` mechanism without the cost model).
   * ``strict_pre``  — Milvus-like always-pre-filtering.
   * ``post``        — always post-filtering.
 """
@@ -134,6 +136,24 @@ class QueryStats:
                    precision_in=np.zeros(0), faults=np.zeros(0, np.int64),
                    retries=np.zeros(0, np.int64),
                    degraded=np.zeros(0, np.int64), disk=None)
+
+
+@dataclasses.dataclass
+class SearchGroup:
+    """One coalesced batch of :meth:`FilteredANNEngine.execute`: the
+    queries (``idxs`` into the request batch) routed to one mechanism at
+    one pool-size bucket and config. ``params``/``entries``/``seed_pages``
+    are set for the graph-search mechanisms (in/post) only."""
+    mech: str
+    eff_l: int
+    scfg: SearchConfig
+    idxs: list
+    strict: bool
+    queries: np.ndarray
+    qfilter: object
+    params: "search.SearchParams | None" = None
+    entries: np.ndarray | None = None
+    seed_pages: np.ndarray | None = None
 
 
 class FilteredANNEngine:
@@ -531,7 +551,7 @@ class FilteredANNEngine:
             return full
         elif scfg.policy == "basefilter":
             mech = "pre" if plan.selectivity < 0.01 else "post"
-        elif scfg.policy == "strict_in":
+        elif scfg.policy in ("strict_in", "spec_in"):
             mech = "in"
         elif scfg.policy == "strict_pre":
             mech = "pre"
@@ -559,10 +579,7 @@ class FilteredANNEngine:
         batches. Returns ``(ids_list, dists_list, QueryStats)`` where the
         i-th list entries are (k_i,) arrays — per-query k may differ.
         """
-        queries = np.asarray(queries, np.float32)
-        if queries.shape[1] != self.store.dim:
-            pad = self.store.dim - queries.shape[1]
-            queries = np.pad(queries, ((0, 0), (0, pad)))
+        queries = self._padded(queries)
         B = queries.shape[0]
         assert len(selectors) == B and len(scfgs) == B
         cfg = self.config
@@ -591,38 +608,27 @@ class FilteredANNEngine:
             degraded=np.zeros(B, np.int64),
         )
 
-        groups: dict = {}
-        for i, r in enumerate(routes):
-            eff = 1 << max(5, math.ceil(math.log2(max(r.effective_l, 1))))
-            eff = min(eff, scfgs[i].max_pool)
-            groups.setdefault((r.mechanism, eff, scfgs[i]), []).append(i)
-
         ds = self.disk_store
         disk_before = ds.snapshot() if ds is not None else None
-        for (mech, eff_l, scfg), idxs in groups.items():
-            strict = scfg.policy in ("strict_in", "strict_pre", "basefilter")
-            # keep batch assembly on the host: the raw group width is
-            # composition-dependent, and the pipelined driver pads it to a
-            # power-of-two bucket before anything touches the device
-            sub_q = np.ascontiguousarray(queries[idxs])
-            sub_sel = [selectors[i] for i in idxs]
-            sub_qf = stack_filters([plans[i].qfilter for i in idxs])
+        for g in self._groups(queries, selectors, scfgs, plans, routes):
+            idxs, scfg = g.idxs, g.scfg
             if ds is not None:
                 # arm the disk tier with this group's knobs: the fault
                 # plan (host draws must mirror the traced ladder) and the
                 # read-ahead window (depth − 1 scales it)
                 ds.fault_plan = scfg.fault_plan
                 ds.prefetch_depth = scfg.prefetch_depth
-            if mech == "pre":
+            if g.mech == "pre":
                 # the re-rank pool scales with the superset's precision
                 # (effective_l = L/p_pre + L): a speculative AND scans only
                 # its cheapest branch, so only ~p_pre of the superset is
                 # valid — L+δ alone would starve multi-predicate queries
                 pp = prefilter.PrefilterParams(
-                    l_rerank=eff_l + scfg.l_rerank_delta, k=scfg.k)
+                    l_rerank=g.eff_l + scfg.l_rerank_delta, k=scfg.k)
                 res = prefilter.prefilter_search(
-                    self.store, self.codes, self.codebook, sub_sel, sub_qf,
-                    sub_q, pp, speculative=not strict,
+                    self.store, self.codes, self.codebook,
+                    [selectors[i] for i in idxs], g.qfilter, g.queries, pp,
+                    speculative=not g.strict,
                     host_fetch=ds.fetch_host if ds is not None else None)
                 for j, i in enumerate(idxs):
                     out_ids[i] = np.asarray(res.ids[j])
@@ -631,50 +637,24 @@ class FilteredANNEngine:
                     stats.dist_comps[i] = int(res.dist_comps[j])
                     stats.n_valid[i] = int(res.n_valid[j])
             else:
-                mode = {"in": "strict_in" if scfg.policy == "strict_in"
-                        else "spec_in", "post": "post"}[mech]
-                sp = search.SearchParams(
-                    l_search=eff_l, k=scfg.k, beam_width=scfg.beam_width,
-                    max_hops=scfg.max_hops, mode=mode, l_valid=scfg.l,
-                    prefetch_depth=scfg.prefetch_depth,
-                    fault_plan=scfg.fault_plan)
-                entries = None
-                seed_pages = np.zeros(len(idxs), np.int64)
-                if mode == "strict_in":
-                    # strict in-filtering needs exactly-valid entry seeds:
-                    # its pool admits only valid records, so starting at the
-                    # medoid strands the search whenever no valid record is
-                    # reachable through valid nodes (the baseline's analogue
-                    # of Filtered-DiskANN's per-label entry points). The
-                    # seeds come from a query-time attribute-index scan, so
-                    # its pages are charged to the query — arbitrary range /
-                    # composite filters cannot be precomputed offline.
-                    ents = np.full((len(idxs), 4), -1, np.int32)
-                    for j, i in enumerate(idxs):
-                        seeds, pages = _strict_seed_ids(sub_sel[j],
-                                                        self.medoid, 4)
-                        ents[j, :seeds.size] = seeds
-                        seed_pages[j] = pages
-                    entries = ents
                 # the bucketed pipelined driver: chunked hops + straggler
                 # compaction (search.filtered_search_pipelined); hop_chunk=0
                 # falls back to the single-shot jit
                 res = search.filtered_search_pipelined(
-                    self.store, self.codes, self.codebook, self.mem, sub_qf,
-                    sub_q, self.medoid, sp, entries=entries,
-                    hop_chunk=scfg.hop_chunk,
+                    self.store, self.codes, self.codebook, self.mem,
+                    g.qfilter, g.queries, self.medoid, g.params,
+                    entries=g.entries, hop_chunk=scfg.hop_chunk,
                     **({"fetch_fn": ds.fetch_callable}
                        if ds is not None else
                        {"runner": self._runner}
                        if self._runner is not None else {}))
-                prefetch = np.array([plans[i].pages_prefetch for i in idxs]) \
-                    if mode == "spec_in" else 0
+                spec_in = g.params.mode == "spec_in"
                 for j, i in enumerate(idxs):
                     out_ids[i] = np.asarray(res.ids[j])
                     out_d[i] = np.asarray(res.dists[j])
                     stats.io_pages[i] = int(res.io_pages[j]) + int(
-                        seed_pages[j]) + (
-                        int(prefetch[j]) if mode == "spec_in" else 0)
+                        g.seed_pages[j]) + (
+                        int(plans[i].pages_prefetch) if spec_in else 0)
                     stats.dist_comps[i] = int(res.dist_comps[j])
                     stats.hops[i] = int(res.hops[j])
                     stats.fp_explored[i] = int(res.fp_explored[j])
@@ -686,6 +666,69 @@ class FilteredANNEngine:
         if ds is not None:
             stats.disk = ds.delta(disk_before, ds.snapshot())
         return out_ids, out_d, stats
+
+    def _padded(self, queries) -> np.ndarray:
+        """Queries zero-padded to the store's (pq_m-aligned) dim."""
+        queries = np.asarray(queries, np.float32)
+        pad = self.store.dim - queries.shape[1]
+        return np.pad(queries, ((0, 0), (0, pad))) if pad else queries
+
+    def plan_groups(self, queries: np.ndarray,
+                    selectors: Sequence[Selector],
+                    scfgs: Sequence[SearchConfig]) -> list:
+        """The coalesced batches :meth:`execute` runs, with the exact
+        inputs each one hands its search path — so a caller can replay a
+        group through another implementation (``search.filtered_search_ref``)
+        on identical parameters."""
+        queries = self._padded(queries)
+        cfg = self.config
+        plans = [s.plan(cfg.ql, cfg.cap, cfg.qr) for s in selectors]
+        routes = [self._route(p, sc) for p, sc in zip(plans, scfgs)]
+        return list(self._groups(queries, selectors, scfgs, plans, routes))
+
+    def _groups(self, queries, selectors, scfgs, plans, routes):
+        groups: dict = {}
+        for i, r in enumerate(routes):
+            eff = 1 << max(5, math.ceil(math.log2(max(r.effective_l, 1))))
+            eff = min(eff, scfgs[i].max_pool)
+            groups.setdefault((r.mechanism, eff, scfgs[i]), []).append(i)
+        for (mech, eff_l, scfg), idxs in groups.items():
+            strict = scfg.policy in ("strict_in", "strict_pre", "basefilter")
+            # keep batch assembly on the host: the raw group width is
+            # composition-dependent, and the pipelined driver pads it to a
+            # power-of-two bucket before anything touches the device
+            g = SearchGroup(
+                mech=mech, eff_l=eff_l, scfg=scfg, idxs=idxs, strict=strict,
+                queries=np.ascontiguousarray(queries[idxs]),
+                qfilter=stack_filters([plans[i].qfilter for i in idxs]))
+            if mech == "pre":
+                yield g
+                continue
+            mode = {"in": "strict_in" if scfg.policy == "strict_in"
+                    else "spec_in", "post": "post"}[mech]
+            g.params = search.SearchParams(
+                l_search=eff_l, k=scfg.k, beam_width=scfg.beam_width,
+                max_hops=scfg.max_hops, mode=mode, l_valid=scfg.l,
+                prefetch_depth=scfg.prefetch_depth,
+                fault_plan=scfg.fault_plan)
+            g.seed_pages = np.zeros(len(idxs), np.int64)
+            if mode == "strict_in":
+                # strict in-filtering needs exactly-valid entry seeds:
+                # its pool admits only valid records, so starting at the
+                # medoid strands the search whenever no valid record is
+                # reachable through valid nodes (the baseline's analogue
+                # of Filtered-DiskANN's per-label entry points). The
+                # seeds come from a query-time attribute-index scan, so
+                # its pages are charged to the query — arbitrary range /
+                # composite filters cannot be precomputed offline.
+                ents = np.full((len(idxs), 4), -1, np.int32)
+                for j, i in enumerate(idxs):
+                    seeds, pages = _strict_seed_ids(selectors[i],
+                                                    self.medoid, 4)
+                    ents[j, :seeds.size] = seeds
+                    g.seed_pages[j] = pages
+                g.entries = ents
+            yield g
 
     # ------------------------------------------------------------------
     def approx_scan(self, queries: np.ndarray,
@@ -701,10 +744,7 @@ class FilteredANNEngine:
         superset membership gate over *every* id — no valid record can be
         excluded), results are exactly verified (no false positives), and
         served queries are flagged via ``stats.degraded``."""
-        queries = np.asarray(queries, np.float32)
-        if queries.shape[1] != self.store.dim:
-            pad = self.store.dim - queries.shape[1]
-            queries = np.pad(queries, ((0, 0), (0, pad)))
+        queries = self._padded(queries)
         B = queries.shape[0]
         assert len(selectors) == B and len(scfgs) == B
         cfg = self.config

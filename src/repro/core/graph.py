@@ -277,6 +277,32 @@ def _dedup_ascending(cands: jax.Array, self_ids: jax.Array) -> jax.Array:
     return jnp.where(x == big, -1, x)
 
 
+def prune_distances(data: jax.Array, p_ids: jax.Array,
+                    cand_ids: jax.Array):
+    """The domination scan's inputs for a batch of prune rows.
+
+    Returns ``(dp_s, dcc, ids_s)``: candidate→point distances stable-sorted
+    ascending per row (+inf for the -1 pads), the (B, C, C) pairwise
+    candidate distances in that sorted order, and the ids in that order —
+    what :func:`kernels.ops.prune_scan` consumes."""
+    valid = cand_ids >= 0
+    cv = data[jnp.where(valid, cand_ids, 0)]                 # (B, C, D)
+    pv = data[p_ids]                                         # (B, D)
+    d_p = jnp.sum((cv - pv[:, None, :]) ** 2, axis=-1)
+    d_p = jnp.where(valid, d_p, jnp.inf)
+    order = jnp.argsort(d_p, axis=1)                         # stable
+    dp_s = jnp.take_along_axis(d_p, order, axis=1)
+    ids_s = jnp.take_along_axis(cand_ids, order, axis=1)
+    cv_s = jnp.take_along_axis(cv, order[:, :, None], axis=1)
+    # pairwise candidate distances in sorted space (norm expansion, with
+    # float32 products: the TPU's default matmul rounds to bfloat16)
+    sq = jnp.sum(cv_s * cv_s, axis=-1)                       # (B, C)
+    dcc = sq[:, :, None] + sq[:, None, :] \
+        - 2.0 * jnp.einsum("bcd,bed->bce", cv_s, cv_s,
+                           precision=jax.lax.Precision.HIGHEST)
+    return dp_s, jnp.maximum(dcc, 0.0), ids_s
+
+
 @functools.partial(jax.jit, static_argnames=("r", "alpha"))
 def robust_prune_batch(data: jax.Array, p_ids: jax.Array, cand_ids: jax.Array,
                        r: int, alpha: float) -> jax.Array:
@@ -288,21 +314,8 @@ def robust_prune_batch(data: jax.Array, p_ids: jax.Array, cand_ids: jax.Array,
     matching the sequential reference's output row layout.
     """
     a2 = float(alpha) * float(alpha)
-    b, c = cand_ids.shape
-    valid = cand_ids >= 0
-    cv = data[jnp.where(valid, cand_ids, 0)]                 # (B, C, D)
-    pv = data[p_ids]                                         # (B, D)
-    d_p = jnp.sum((cv - pv[:, None, :]) ** 2, axis=-1)
-    d_p = jnp.where(valid, d_p, jnp.inf)
-    order = jnp.argsort(d_p, axis=1)                         # stable
-    dp_s = jnp.take_along_axis(d_p, order, axis=1)
-    ids_s = jnp.take_along_axis(cand_ids, order, axis=1)
-    cv_s = jnp.take_along_axis(cv, order[:, :, None], axis=1)
-    # pairwise candidate distances in sorted space (norm expansion)
-    sq = jnp.sum(cv_s * cv_s, axis=-1)                       # (B, C)
-    dcc = sq[:, :, None] + sq[:, None, :] \
-        - 2.0 * jnp.einsum("bcd,bed->bce", cv_s, cv_s)
-    dcc = jnp.maximum(dcc, 0.0)
+    b = cand_ids.shape[0]
+    dp_s, dcc, ids_s = prune_distances(data, p_ids, cand_ids)
     keep_s = ops.prune_scan(dp_s, dcc, a2, r)                # (B, C) bool
     rank = jnp.cumsum(keep_s.astype(jnp.int32), axis=1) - 1
     rows = jnp.full((b, r), -1, jnp.int32)
@@ -405,8 +418,12 @@ def _pad_batch(ids: np.ndarray, width: int) -> tuple[np.ndarray, np.ndarray]:
 
 
 def _prune_rows(data_dev, adj_ext, targets: np.ndarray, srcs: np.ndarray,
-                r: int, alpha: float, chunk: int = 4096):
-    """Re-prune overflowing rows over (old row ∪ pending sources)."""
+                r: int, alpha: float, chunk: int = 4096, prune=None):
+    """Re-prune overflowing rows over (old row ∪ pending sources).
+    ``prune(data, ids, cand) -> rows`` replaces :func:`robust_prune_batch`
+    (the sharded build passes its mesh-replicated form)."""
+    if prune is None:
+        prune = functools.partial(robust_prune_batch, r=r, alpha=alpha)
     dump = adj_ext.shape[0] - 1
     for s in range(0, targets.shape[0], chunk):
         t = targets[s:s + chunk]
@@ -422,7 +439,7 @@ def _prune_rows(data_dev, adj_ext, targets: np.ndarray, srcs: np.ndarray,
         t_dev = jnp.asarray(t)
         cand = jnp.concatenate([adj_ext[t_dev], jnp.asarray(sc)], axis=1)
         cand = _dedup_ascending(cand, t_dev)
-        rows = robust_prune_batch(data_dev, t_dev, cand, r=r, alpha=alpha)
+        rows = prune(data_dev, t_dev, cand)
         adj_ext = adj_ext.at[t_dev].set(rows)
     return adj_ext
 
@@ -465,8 +482,9 @@ def apply_pruned_rows(adj_ext: jax.Array, ids: jax.Array, live: jax.Array,
 
 
 def _drain_overflow(data_dev, adj_ext, st, ss, overflow, n_rows: int,
-                    r: int, alpha: float):
-    """Drain a batch's pending reverse-edge overflow rounds."""
+                    r: int, alpha: float, prune=None):
+    """Drain a batch's pending reverse-edge overflow rounds (``prune`` as
+    in :func:`_prune_rows`)."""
     # small per-round source cap: overflow counts are heavy-tailed (most
     # targets receive a handful of pending edges), so a narrow candidate
     # width r+8 keeps the O(C²·D) prune cheap; rare hot targets just take
@@ -482,7 +500,8 @@ def _drain_overflow(data_dev, adj_ext, st, ss, overflow, n_rows: int,
         if grouped is None:
             break
         targets, srcs, (lt, ls) = grouped
-        adj_ext = _prune_rows(data_dev, adj_ext, targets, srcs, r, alpha)
+        adj_ext = _prune_rows(data_dev, adj_ext, targets, srcs, r, alpha,
+                              prune=prune)
         if lt.size == 0:
             break
         pad = _pow2_pad(lt.size) - lt.size

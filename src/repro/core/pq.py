@@ -13,6 +13,11 @@ import jax
 import jax.numpy as jnp
 
 
+# float32 products at full precision: the TPU's default for a float32
+# matmul is a single bfloat16 pass, which blurs nearest-centroid choices
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 class PQCodebook(NamedTuple):
     centroids: jax.Array   # (M, ksub, dsub) float32
     dim: int               # original dimensionality (M * dsub, possibly padded)
@@ -27,12 +32,12 @@ def _kmeans_subspace(key, x, ksub: int, iters: int):
     def step(cents, _):
         # assign
         d = (jnp.sum(x * x, 1, keepdims=True)
-             - 2.0 * x @ cents.T
+             - 2.0 * jnp.matmul(x, cents.T, precision=_EXACT)
              + jnp.sum(cents * cents, 1)[None, :])
         assign = jnp.argmin(d, axis=1)
         onehot = jax.nn.one_hot(assign, ksub, dtype=x.dtype)      # (N, ksub)
         counts = onehot.sum(0)                                    # (ksub,)
-        sums = onehot.T @ x                                       # (ksub, dsub)
+        sums = jnp.matmul(onehot.T, x, precision=_EXACT)          # (ksub, dsub)
         new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None],
                         cents)
         return new, None
@@ -65,7 +70,7 @@ def encode_pq(codebook: PQCodebook, data) -> jax.Array:
 
     def enc(x_m, c_m):   # (N, dsub), (ksub, dsub)
         d = (jnp.sum(x_m * x_m, 1, keepdims=True)
-             - 2.0 * x_m @ c_m.T
+             - 2.0 * jnp.matmul(x_m, c_m.T, precision=_EXACT)
              + jnp.sum(c_m * c_m, 1)[None, :])
         return jnp.argmin(d, axis=1)
 

@@ -47,12 +47,32 @@ class SynthFilteredDataset:
 def make_filtered_dataset(n: int = 20000, d: int = 48, n_queries: int = 64,
                           n_labels: int = 200, avg_labels: float = 4.0,
                           n_clusters: int = 32, zipf_a: float = 1.3,
-                          seed: int = 0) -> SynthFilteredDataset:
+                          seed: int = 0,
+                          intrinsic_dim: int | None = None
+                          ) -> SynthFilteredDataset:
+    """``intrinsic_dim`` confines each cluster's spread to a random
+    subspace of that many dimensions (per cluster), the low intrinsic
+    dimension of real embeddings; left None, the spread is isotropic in
+    all ``d``, where every same-cluster point is nearly equidistant."""
     rng = np.random.default_rng(seed)
     centers = rng.normal(0, 1.0, (n_clusters, d)).astype(np.float32)
     assign = rng.integers(0, n_clusters, n)
-    vectors = (centers[assign]
-               + rng.normal(0, 0.35, (n, d))).astype(np.float32)
+    if intrinsic_dim is None:
+        def spread(a):
+            return rng.normal(0, 0.35, (a.size, d))
+    else:
+        basis = rng.normal(0, intrinsic_dim ** -0.5,
+                           (n_clusters, intrinsic_dim, d)).astype(np.float32)
+
+        def spread(a):
+            z = rng.normal(0, 0.35, (a.size, intrinsic_dim)).astype(
+                np.float32)
+            out = np.empty((a.size, d), np.float32)
+            for c in range(n_clusters):
+                rows = a == c
+                out[rows] = z[rows] @ basis[c]
+            return out
+    vectors = (centers[assign] + spread(assign)).astype(np.float32)
 
     # Zipf labels: label popularity ~ 1/rank^a
     ranks = np.arange(1, n_labels + 1, dtype=np.float64)
@@ -69,8 +89,7 @@ def make_filtered_dataset(n: int = 20000, d: int = 48, n_queries: int = 64,
     values = rng.lognormal(6.0, 0.8, n).astype(np.float32)
 
     qassign = rng.integers(0, n_clusters, n_queries)
-    queries = (centers[qassign]
-               + rng.normal(0, 0.35, (n_queries, d))).astype(np.float32)
+    queries = (centers[qassign] + spread(qassign)).astype(np.float32)
     # query labels drawn from the same popularity law (1-3 each)
     query_labels = []
     for _ in range(n_queries):

@@ -13,14 +13,15 @@ repo already bitcasts its uint32 bit-words to int32 at the kernel boundary
 ``(w >> k) & 1`` extracts bits correctly even for the sign bit, so signed
 words are bitwise-equivalent for set/test.
 
-One program per batch row. The C slot lanes are walked with a fori_loop;
-the scalar slot is pulled out of the (1, C) vector with the broadcasted-iota
-one-hot + masked-sum idiom (same as prune_scan — TPU has no cheap dynamic
-scalar reads from VMEM vectors). Each step ORs a one-hot-by-word
-contribution row into a (1, NW) accumulator initialized from the input
-words, so duplicate slots and already-set bits are naturally idempotent.
-Out-of-range slots (< 0 or >= NW*32) contribute nothing — callers encode
-"skip this lane" as any such sentinel.
+One program per batch row, walking the row's C slots in order. The slots
+sit in scalar memory; each in-range slot reads one (8, 128) word tile of
+the row from VMEM, ORs its bit in through a one-hot lane/sublane select,
+and writes the tile back, so a call costs O(C) tile updates whatever the
+table width. The word table is laid out (B, NW/1024, 8, 128) for it (NW
+pads to whole tiles): a dynamic index on a leading axis is what the chip
+loads and stores cheaply. Duplicate slots and already-set bits are
+naturally idempotent. Out-of-range slots (< 0 or >= NW*32) contribute
+nothing — callers encode "skip this lane" as any such sentinel.
 """
 from __future__ import annotations
 
@@ -29,24 +30,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _or_scatter_kernel(words_ref, slots_ref, out_ref):
-    words = words_ref[...]                              # (1, NW) int32
-    slots = slots_ref[...]                              # (1, C) int32
-    nw = words.shape[1]
-    c = slots.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
-    word_ix = jax.lax.broadcasted_iota(jnp.int32, (1, nw), 1)
+_TILE_WORDS = 8 * 128
 
-    def body(j, acc):
-        sel = lane == j                                 # (1, C) one-hot
-        s = jnp.sum(jnp.where(sel, slots, 0))
-        valid = (s >= 0) & (s < nw * 32)
-        bit = jnp.where(valid, jax.lax.shift_left(jnp.int32(1), s & 31), 0)
-        return acc | jnp.where(word_ix == (s >> 5), bit, 0)
 
-    out_ref[...] = jax.lax.fori_loop(0, c, body, words)
+def _or_scatter_kernel(slots_ref, words_ref, out_ref, *, n_bits: int):
+    out_ref[...] = words_ref[...]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+
+    def body(j, carry):
+        s = slots_ref[0, 0, j]
+
+        @pl.when((s >= 0) & (s < n_bits))
+        def _():
+            w = s >> 5
+            tile = w // _TILE_WORDS
+            r = w % _TILE_WORDS
+            hit = (sub == r // 128) & (lane == r % 128)
+            bit = jax.lax.shift_left(jnp.int32(1), s & 31)
+            out_ref[0, tile] = out_ref[0, tile] | jnp.where(hit, bit, 0)
+
+        return carry
+
+    jax.lax.fori_loop(0, slots_ref.shape[2], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -58,14 +67,19 @@ def or_scatter(words: jax.Array, slots: jax.Array, *,
     slot of row b."""
     b, nw = words.shape
     c = slots.shape[1]
-    return pl.pallas_call(
-        _or_scatter_kernel,
+    n_tiles = -(-nw // _TILE_WORDS)
+    tiles = jnp.pad(words.astype(jnp.int32),
+                    ((0, 0), (0, n_tiles * _TILE_WORDS - nw)))
+    tiles = tiles.reshape(b, n_tiles, 8, 128)
+    block = pl.BlockSpec((1, n_tiles, 8, 128), lambda i: (i, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_or_scatter_kernel, n_bits=nw * 32),
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, nw), lambda i: (i, 0)),
-            pl.BlockSpec((1, c), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, nw), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nw), jnp.int32),
+        in_specs=[pl.BlockSpec((1, 1, c), lambda i: (i, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(tiles.shape, jnp.int32),
         interpret=interpret,
-    )(words.astype(jnp.int32), slots.astype(jnp.int32))
+    )(slots.astype(jnp.int32).reshape(b, 1, c), tiles)
+    return out.reshape(b, n_tiles * _TILE_WORDS)[:, :nw]

@@ -5,20 +5,11 @@ touches jax device state."""
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed in jax 0.4.34; older versions default to Auto anyway
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    _AXIS_KW = lambda n: {}
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
-    import numpy as np
-    devices = np.asarray(jax.devices()[:int(np.prod(shape))]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -181,7 +181,7 @@ def _sp_decode_core(cfg: ModelConfig, q, k_new, v_new, cache: KVCache):
 
     rep = P(b_ax, None, None, None)
     seq = P(b_ax, "model", None, None)
-    from repro.utils.compat import shard_map
+    from jax import shard_map
     f = shard_map(body, mesh=mesh,
                   in_specs=(rep, rep, rep, seq, seq, P()),
                   out_specs=(rep, seq, seq), check_vma=False)

@@ -39,8 +39,7 @@ def compressed_psum_grads(grads, error_fb, axis_name: str):
 
     Must run inside shard_map/pmap over `axis_name`. Returns
     (mean_grads, new_error_fb)."""
-    from repro.utils.compat import axis_size
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
 
     def one(g, e):
         g_fb = g.astype(jnp.float32) + e

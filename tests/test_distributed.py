@@ -45,7 +45,7 @@ def body(q, k, v, pos):
     return SP.sp_decode_attention_local(q, k, v, pos, n_kv=hkv,
                                         axis_name="model")
 
-from repro.utils.compat import shard_map
+from jax import shard_map
 f = jax.jit(shard_map(
     body, mesh=mesh,
     in_specs=(P(), P(None, "model", None, None), P(None, "model", None, None),
@@ -77,7 +77,7 @@ pos = jnp.asarray(13, jnp.int32)
 def body(kc, vc, kn, vn, pos):
     return SP.sp_cache_update(kc, vc, kn, vn, pos, axis_name="model")
 
-from repro.utils.compat import shard_map
+from jax import shard_map
 f = jax.jit(shard_map(
     body, mesh=mesh,
     in_specs=(P(None, "model", None, None), P(None, "model", None, None),
@@ -148,7 +148,7 @@ def body(g, e):
         {"w": g["w"][0]}, {"w": e["w"]}, "data")
     return mean, {"w": new_e["w"][None]}     # stack per-device error states
 
-from repro.utils.compat import shard_map
+from jax import shard_map
 f = jax.jit(shard_map(
     body, mesh=mesh,
     in_specs=({"w": P("data", None, None)}, {"w": P()}),

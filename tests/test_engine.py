@@ -122,3 +122,27 @@ def test_engine_calibrate_roundtrip(built):
     finally:
         e.calibrate(None)          # shared engine: leave no state behind
     assert e.calibration is None
+
+
+@pytest.mark.parametrize("policy,mode", [("spec_in", "spec_in"),
+                                         ("strict_in", "strict_in"),
+                                         ("post", "post")])
+def test_plan_groups_replay_execute(built, policy, mode):
+    """plan_groups hands out exactly what execute runs: each group
+    replayed through the jnp oracle returns execute's ids, and a pinned
+    policy routes every query to its one mechanism."""
+    from repro.core import search
+    ds, e = built
+    sels = make_selectors(ds, e, "hybrid")
+    scfgs = [eng.SearchConfig(k=10, l=32, max_hops=200, policy=policy)
+             ] * len(sels)
+    ids, _, stats = e.execute(ds.queries, sels, scfgs)
+    groups = e.plan_groups(ds.queries, sels, scfgs)
+    assert sorted(i for g in groups for i in g.idxs) == list(range(len(sels)))
+    for g in groups:
+        assert g.params.mode == mode
+        res = search.filtered_search_ref(
+            e.store, e.codes, e.codebook, e.mem, g.qfilter, g.queries,
+            e.medoid, g.params, entries=g.entries)
+        for j, i in enumerate(g.idxs):
+            np.testing.assert_array_equal(np.asarray(res.ids[j]), ids[i])

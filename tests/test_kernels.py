@@ -114,7 +114,9 @@ def _rand_hop_inputs(rng, b, c, m=8, k=256, f=3, ql=8, nr=4):
             range_field, jnp.asarray(lo), jnp.asarray(hi))
 
 
-@pytest.mark.parametrize("b,c", [(1, 7), (3, 64), (4, 300), (2, 520)])
+# b=9 and b=13 span two 8-row blocks with padded rows in the second
+@pytest.mark.parametrize("b,c", [(1, 7), (3, 64), (4, 300), (2, 520),
+                                 (9, 130), (13, 40)])
 @pytest.mark.parametrize("seed", [0, 1])
 def test_hop_fused_matches_ref(b, c, seed):
     rng = np.random.default_rng(seed * 100 + b * c)
@@ -224,8 +226,9 @@ def _or_scatter_numpy(words, slots):
     return out
 
 
+# nw=1500 spans two (8, 128) word tiles, the second one padded
 @pytest.mark.parametrize("b,nw,c", [(1, 1, 4), (3, 8, 33), (7, 4, 128),
-                                    (2, 32, 300)])
+                                    (2, 32, 300), (9, 1500, 200)])
 @pytest.mark.parametrize("seed", [0, 1])
 def test_or_scatter_matches_ref(b, nw, c, seed):
     rng = np.random.default_rng(seed * 997 + b * nw * c)
@@ -277,7 +280,7 @@ def _prune_inputs(rng, b, c, pad_frac=0.3):
 
 
 @pytest.mark.parametrize("b,c,r", [(1, 16, 4), (8, 48, 12), (5, 96, 32),
-                                   (2, 33, 5)])
+                                   (2, 33, 5), (11, 130, 24)])
 @pytest.mark.parametrize("alpha", [1.0, 1.2])
 def test_prune_scan_matches_ref(b, c, r, alpha):
     rng = np.random.default_rng(b * c + r)

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 from repro.core import engine as eng
@@ -221,9 +222,9 @@ RES_CAP_HOPS = 77     # max_hops·W == 77: a dim no other array in the trace has
 
 
 def _sub_jaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax.extend.core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax.extend.core.Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for x in v:
