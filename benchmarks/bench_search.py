@@ -27,10 +27,6 @@ the three paths return bit-identical results, asserted here):
 
 ``--smoke`` builds a tiny corpus and runs every mode end-to-end with no
 perf bars and no JSON — the bitrot check ``scripts/test_fast.sh`` runs.
-``--active-trace`` additionally records per-hop active-query counts, the
-driver's compaction buckets, and the modeled SSD latency with/without
-prefetch (``io_sim.IOModel.latency_us``) for the spec_in W=1 config.
-
 ``--fault-plan`` (default: the committed 10% page-fault operating point,
 ``rate=0.1,seed=7``; pass ``none`` to skip) re-times the pipelined path
 under seeded fault injection (core/faults.py) and reports degraded-mode
@@ -167,49 +163,6 @@ def _assert_bit_identical(a: S.SearchResult, b: S.SearchResult, tag: str):
     for field in S.SearchResult._fields:
         av, bv = np.asarray(getattr(a, field)), np.asarray(getattr(b, field))
         assert np.array_equal(av, bv), f"{tag}: {field} diverged"
-
-
-def active_trace(e, ds, smoke: bool, warm_us_per_query: float) -> dict:
-    """Per-hop active-query counts + compaction buckets + modeled SSD
-    latency for the spec_in W=1 config (the straggler-bound case the
-    compaction attacks)."""
-    params = S.SearchParams(l_search=L, k=K, beam_width=1,
-                            max_hops=MAX_HOPS, mode="spec_in")
-    _, qf, queries, entries = _mode_inputs(e, ds, "spec_in")
-    res, chunks = S.filtered_search_pipelined(
-        e.store, e.codes, e.codebook, e.mem, qf, queries, e.medoid, params,
-        entries=entries, collect_trace=True)
-    hops = np.asarray(res.hops)
-    # a query is active at hop t iff its final hop count exceeds t — the
-    # per-hop active width is exact from the counters, no loop probes
-    per_hop_active = [int((hops > t).sum()) for t in range(int(hops.max()))]
-    io = IOModel()
-    mean_hops = float(hops.mean())
-    pages_hop = e.store.pages_dense
-    compute_us = warm_us_per_query
-    modeled = {
-        "t_page_us": io.t_page_us,
-        "mean_dependent_pages": mean_hops * pages_hop,
-        "compute_us_per_query": compute_us,
-        # serial issue order (prefetch_depth=1): read + compute add up
-        "latency_us_prefetch1": io.latency_us(
-            int(round(mean_hops * pages_hop)), 0, prefetch_depth=1,
-            compute_us=compute_us),
-        # double-buffered loop: compute hides behind the in-flight read
-        "latency_us_prefetch2": io.latency_us(
-            int(round(mean_hops * pages_hop)), 0, prefetch_depth=2,
-            compute_us=compute_us),
-    }
-    trace = {"mode": "spec_in", "beam_width": 1,
-             "hop_chunk": S.DEFAULT_HOP_CHUNK,
-             "min_bucket": S.MIN_COMPACT_BUCKET,
-             "per_hop_active": per_hop_active,
-             "chunks": chunks, "modeled": modeled}
-    if not smoke:
-        # the whole point of compaction: the batch thins out long before
-        # the last straggler settles
-        assert per_hop_active[-1] < per_hop_active[0], "no straggler tail?"
-    return trace
 
 
 def _fault_block(e, ds, plan, clean_modes: dict, smoke: bool,
@@ -549,7 +502,6 @@ def run_sharded(out_path: str = OUT_PATH, smoke: bool = False) -> list:
 
 
 def run(out_path: str = OUT_PATH, smoke: bool = False,
-        with_trace: bool = False,
         fault_spec: str | None = FAULT_PLAN_DEFAULT,
         store: str = "device") -> list:
     n = N_SMOKE if smoke else N
@@ -562,7 +514,6 @@ def run(out_path: str = OUT_PATH, smoke: bool = False,
                           "batch": B, "selectivity": SELECTIVITY},
                "modes": {}}
     results = []
-    warm_p_spec_us = 0.0
     for name, mode, w in CONFIGS:
         params = S.SearchParams(l_search=L, k=K, beam_width=w,
                                 max_hops=MAX_HOPS, mode=mode)
@@ -586,8 +537,6 @@ def run(out_path: str = OUT_PATH, smoke: bool = False,
         rec_f = _recall(ds, e, sels, res_f)
         rec_r = _recall(ds, e, sels, res_r)
         speedup = warm_l / warm_f
-        if name == "spec_in":
-            warm_p_spec_us = warm_p * 1e6 / B
         stats = {
             "mode": mode, "beam_width": w,
             "pipelined_ms": warm_p * 1e3, "pipelined_ms_cold": cold_p * 1e3,
@@ -627,9 +576,6 @@ def run(out_path: str = OUT_PATH, smoke: bool = False,
             assert np.array_equal(np.asarray(res_f.explored),
                                   np.asarray(res_r.explored)), name
 
-    if with_trace:
-        payload["active_trace"] = active_trace(e, ds, smoke, warm_p_spec_us)
-
     if fault_spec and fault_spec.lower() != "none":
         payload["fault_plan"] = _fault_block(
             e, ds, parse_plan(fault_spec), payload["modes"], smoke, results)
@@ -665,9 +611,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny end-to-end run, no perf bars / JSON output")
-    ap.add_argument("--active-trace", action="store_true",
-                    help="also record per-hop active counts, compaction "
-                         "buckets and modeled SSD latency (spec_in W=1)")
     ap.add_argument("--fault-plan", default=FAULT_PLAN_DEFAULT,
                     help="seeded FaultPlan spec for the degraded-mode "
                          "block, e.g. 'rate=0.1,seed=7' ('none' to skip)")
@@ -693,7 +636,6 @@ def main():
             print(res.csv())
         return
     for res in run(out_path=args.out, smoke=args.smoke,
-                   with_trace=args.active_trace,
                    fault_spec=args.fault_plan, store=args.store):
         print(res.csv())
 

@@ -10,7 +10,7 @@ PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.bench_build --smo
 # the search smoke doubles as the seeded fault-injection smoke: the
 # default --fault-plan (10% page-fault rate, seed 7) re-runs every mode
 # under injection and asserts the degraded-mode recall floor
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.bench_search --smoke --active-trace --store disk
+PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.bench_search --smoke --store disk
 # serving-tier smoke: degrade-rung calibration + a tiny Poisson
 # open-loop sweep through the threaded SearchServer (no floors)
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.bench_serve --smoke

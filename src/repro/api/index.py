@@ -37,6 +37,7 @@ from repro.core.ranges import MultiRangeStore, RangeStore
 from repro.core.records import RecordStore
 from repro.core.selectors import (InMemory, MaskSelector, MatchAllSelector,
                                   Selector)
+from repro.utils.spans import span
 
 _META_FILE = "index_meta.json"
 _FORMAT = 2          # checkpoint format: 2 = schema-first multi-field
@@ -353,31 +354,34 @@ class Index:
                               with_metadata)
 
     def _prepare(self, requests, scfgs):
-        queries = np.stack([np.asarray(r.query, np.float32).reshape(-1)
-                            for r in requests])
-        if queries.shape[1] > self.dim:
-            raise ValueError(f"query dim {queries.shape[1]} exceeds index "
-                             f"dim {self.dim}")
-        selectors = [self.compile_filter(r.filter) for r in requests]
-        if scfgs is None:
-            scfgs = [self._resolve_scfg(r) for r in requests]
-        else:
-            scfgs = list(scfgs)
-            assert len(scfgs) == len(requests)
-        return queries, selectors, scfgs
+        with span("index.prepare", size=len(requests)):
+            queries = np.stack([np.asarray(r.query, np.float32).reshape(-1)
+                                for r in requests])
+            if queries.shape[1] > self.dim:
+                raise ValueError(f"query dim {queries.shape[1]} exceeds "
+                                 f"index dim {self.dim}")
+            selectors = [self.compile_filter(r.filter) for r in requests]
+            if scfgs is None:
+                scfgs = [self._resolve_scfg(r) for r in requests]
+            else:
+                scfgs = list(scfgs)
+                assert len(scfgs) == len(requests)
+            return queries, selectors, scfgs
 
     def _assemble(self, requests, ids, dists, stats, with_stats,
                   with_metadata):
-        results = []
-        for i in range(len(requests)):
-            meta = [self.record_metadata(int(x))
-                    if with_metadata and x >= 0 else None
-                    for x in ids[i]]
-            results.append(SearchResult(
-                ids=np.asarray(ids[i]), dists=np.asarray(dists[i]),
-                metadata=meta,
-                stats=RequestStats.from_query_stats(stats, i)))
-        return (results, stats) if with_stats else results
+        with span("index.assemble", size=len(requests),
+                  metadata=with_metadata):
+            results = []
+            for i in range(len(requests)):
+                meta = [self.record_metadata(int(x))
+                        if with_metadata and x >= 0 else None
+                        for x in ids[i]]
+                results.append(SearchResult(
+                    ids=np.asarray(ids[i]), dists=np.asarray(dists[i]),
+                    metadata=meta,
+                    stats=RequestStats.from_query_stats(stats, i)))
+            return (results, stats) if with_stats else results
 
     def search(self, request: SearchRequest) -> SearchResult:
         return self.search_batch([request])[0]

@@ -36,6 +36,7 @@ from repro.core.ranges import (MultiRangeStore, RangeStore,
 from repro.core import records as records_mod
 from repro.core.records import RecordStore, make_record_store
 from repro.core.selectors import (InMemory, Selector, stack_filters)
+from repro.utils.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -584,8 +585,11 @@ class FilteredANNEngine:
         assert len(selectors) == B and len(scfgs) == B
         cfg = self.config
 
-        plans = [s.plan(cfg.ql, cfg.cap, cfg.qr) for s in selectors]
-        routes = [self._route(p, sc) for p, sc in zip(plans, scfgs)]
+        with span("engine.route", size=B):
+            plans = [s.plan(cfg.ql, cfg.cap, cfg.qr) for s in selectors]
+            routes = [self._route(p, sc) for p, sc in zip(plans, scfgs)]
+            groups = list(self._groups(queries, selectors, scfgs, plans,
+                                       routes))
 
         out_ids: list = [None] * B
         out_d: list = [None] * B
@@ -610,62 +614,71 @@ class FilteredANNEngine:
 
         ds = self.disk_store
         disk_before = ds.snapshot() if ds is not None else None
-        for g in self._groups(queries, selectors, scfgs, plans, routes):
-            idxs, scfg = g.idxs, g.scfg
-            if ds is not None:
-                # arm the disk tier with this group's knobs: the fault
-                # plan (host draws must mirror the traced ladder) and the
-                # read-ahead window (depth − 1 scales it)
-                ds.fault_plan = scfg.fault_plan
-                ds.prefetch_depth = scfg.prefetch_depth
-            if g.mech == "pre":
-                # the re-rank pool scales with the superset's precision
-                # (effective_l = L/p_pre + L): a speculative AND scans only
-                # its cheapest branch, so only ~p_pre of the superset is
-                # valid — L+δ alone would starve multi-predicate queries
-                pp = prefilter.PrefilterParams(
-                    l_rerank=g.eff_l + scfg.l_rerank_delta, k=scfg.k)
-                res = prefilter.prefilter_search(
-                    self.store, self.codes, self.codebook,
-                    [selectors[i] for i in idxs], g.qfilter, g.queries, pp,
-                    speculative=not g.strict,
-                    host_fetch=ds.fetch_host if ds is not None else None)
+        for g in groups:
+            with span("engine.group", mech=g.mech, width=len(g.idxs)):
+                self._run_group(g, selectors, plans, out_ids, out_d, stats)
+        if ds is not None:
+            stats.disk = ds.delta(disk_before, ds.snapshot())
+        return out_ids, out_d, stats
+
+    def _run_group(self, g: SearchGroup, selectors, plans, out_ids, out_d,
+                   stats: QueryStats) -> None:
+        """Run one coalesced group and copy its per-query results out."""
+        idxs, scfg = g.idxs, g.scfg
+        ds = self.disk_store
+        if ds is not None:
+            # arm the disk tier with this group's knobs: the fault plan
+            # (host draws must mirror the traced ladder) and the
+            # read-ahead window (depth − 1 scales it)
+            ds.fault_plan = scfg.fault_plan
+            ds.prefetch_depth = scfg.prefetch_depth
+        if g.mech == "pre":
+            # the re-rank pool scales with the superset's precision
+            # (effective_l = L/p_pre + L): a speculative AND scans only its
+            # cheapest branch, so only ~p_pre of the superset is valid —
+            # L+δ alone would starve multi-predicate queries
+            pp = prefilter.PrefilterParams(
+                l_rerank=g.eff_l + scfg.l_rerank_delta, k=scfg.k)
+            res = prefilter.prefilter_search(
+                self.store, self.codes, self.codebook,
+                [selectors[i] for i in idxs], g.qfilter, g.queries, pp,
+                speculative=not g.strict,
+                host_fetch=ds.fetch_host if ds is not None else None)
+            with span("engine.collect", width=len(idxs)):
                 for j, i in enumerate(idxs):
                     out_ids[i] = np.asarray(res.ids[j])
                     out_d[i] = np.asarray(res.dists[j])
                     stats.io_pages[i] = int(res.io_pages[j])
                     stats.dist_comps[i] = int(res.dist_comps[j])
                     stats.n_valid[i] = int(res.n_valid[j])
-            else:
-                # the bucketed pipelined driver: chunked hops + straggler
-                # compaction (search.filtered_search_pipelined); hop_chunk=0
-                # falls back to the single-shot jit
-                res = search.filtered_search_pipelined(
-                    self.store, self.codes, self.codebook, self.mem,
-                    g.qfilter, g.queries, self.medoid, g.params,
-                    entries=g.entries, hop_chunk=scfg.hop_chunk,
-                    **({"fetch_fn": ds.fetch_callable}
-                       if ds is not None else
-                       {"runner": self._runner}
-                       if self._runner is not None else {}))
-                spec_in = g.params.mode == "spec_in"
-                for j, i in enumerate(idxs):
-                    out_ids[i] = np.asarray(res.ids[j])
-                    out_d[i] = np.asarray(res.dists[j])
-                    stats.io_pages[i] = int(res.io_pages[j]) + int(
-                        g.seed_pages[j]) + (
-                        int(plans[i].pages_prefetch) if spec_in else 0)
-                    stats.dist_comps[i] = int(res.dist_comps[j])
-                    stats.hops[i] = int(res.hops[j])
-                    stats.fp_explored[i] = int(res.fp_explored[j])
-                    stats.explored[i] = int(res.explored[j])
-                    stats.n_valid[i] = int(res.n_valid[j])
-                    stats.faults[i] = int(res.faults[j])
-                    stats.retries[i] = int(res.retries[j])
-                    stats.degraded[i] = int(res.degraded[j])
-        if ds is not None:
-            stats.disk = ds.delta(disk_before, ds.snapshot())
-        return out_ids, out_d, stats
+            return
+        # the bucketed pipelined driver: chunked hops + straggler
+        # compaction (search.filtered_search_pipelined); hop_chunk=0 falls
+        # back to the single-shot jit
+        res = search.filtered_search_pipelined(
+            self.store, self.codes, self.codebook, self.mem,
+            g.qfilter, g.queries, self.medoid, g.params,
+            entries=g.entries, hop_chunk=scfg.hop_chunk,
+            **({"fetch_fn": ds.fetch_callable}
+               if ds is not None else
+               {"runner": self._runner}
+               if self._runner is not None else {}))
+        spec_in = g.params.mode == "spec_in"
+        with span("engine.collect", width=len(idxs)):
+            for j, i in enumerate(idxs):
+                out_ids[i] = np.asarray(res.ids[j])
+                out_d[i] = np.asarray(res.dists[j])
+                stats.io_pages[i] = int(res.io_pages[j]) + int(
+                    g.seed_pages[j]) + (
+                    int(plans[i].pages_prefetch) if spec_in else 0)
+                stats.dist_comps[i] = int(res.dist_comps[j])
+                stats.hops[i] = int(res.hops[j])
+                stats.fp_explored[i] = int(res.fp_explored[j])
+                stats.explored[i] = int(res.explored[j])
+                stats.n_valid[i] = int(res.n_valid[j])
+                stats.faults[i] = int(res.faults[j])
+                stats.retries[i] = int(res.retries[j])
+                stats.degraded[i] = int(res.degraded[j])
 
     def _padded(self, queries) -> np.ndarray:
         """Queries zero-padded to the store's (pq_m-aligned) dim."""

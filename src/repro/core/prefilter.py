@@ -21,6 +21,7 @@ from repro.core import pq as pq_mod
 from repro.core.records import RecordStore
 from repro.core.selectors import (InMemory, QueryFilter, Selector, is_member,
                                   is_member_approx)
+from repro.utils.spans import span
 
 BIG = jnp.float32(1e30)
 INVALID_PENALTY = jnp.float32(1e12)
@@ -173,35 +174,39 @@ def prefilter_search(store: RecordStore, codes, codebook, selectors, qfilters,
 
     for b in range(B):
         sel: Selector = selectors[b]
-        if speculative:
-            cand, pages = sel.pre_filter_approx()
-        else:
-            cand, pages = _strict_scan(sel)
-        cand = cand[:params.max_candidates]
-        pad = -(-max(cand.size, 1) // params.chunk) * params.chunk
-        cand_padded = np.full(pad, -1, np.int32)
-        cand_padded[:cand.size] = cand
-        # index on the host: a device-side row gather is shape-keyed on the
-        # raw batch width and would compile per distinct group composition
-        qf = jax.tree_util.tree_map(lambda x: np.asarray(x)[b], qfilters)
-        top_ids, _ = _pq_topl(codes, codebook, queries[b],
-                              jnp.asarray(cand_padded), cand.size,
-                              params.l_rerank, params.chunk, distance_fn)
-        if host_fetch is None:
-            ids, dists, io, nv = _rerank_verify(store, qf, queries[b],
-                                                top_ids, params)
-        else:
-            tid = np.asarray(top_ids)
-            rec = host_fetch(np.where(tid >= 0, tid, 0))
-            ids, dists, io, nv = _verify_fetched(
-                qf, queries[b], top_ids, jnp.asarray(rec["vectors"]),
-                jnp.asarray(rec["rec_labels"]),
-                jnp.asarray(rec["rec_values"]), params, store.pages_std)
+        with span("prefilter.superset", query=b):
+            if speculative:
+                cand, pages = sel.pre_filter_approx()
+            else:
+                cand, pages = _strict_scan(sel)
+            cand = cand[:params.max_candidates]
+            pad = -(-max(cand.size, 1) // params.chunk) * params.chunk
+            cand_padded = np.full(pad, -1, np.int32)
+            cand_padded[:cand.size] = cand
+        with span("prefilter.dispatch", query=b, cand=int(cand.size)):
+            # index on the host: a device-side row gather is shape-keyed on
+            # the raw batch width and would compile per distinct group
+            # composition
+            qf = jax.tree_util.tree_map(lambda x: np.asarray(x)[b], qfilters)
+            top_ids, _ = _pq_topl(codes, codebook, queries[b],
+                                  jnp.asarray(cand_padded), cand.size,
+                                  params.l_rerank, params.chunk, distance_fn)
+            if host_fetch is None:
+                ids, dists, io, nv = _rerank_verify(store, qf, queries[b],
+                                                    top_ids, params)
+            else:
+                tid = np.asarray(top_ids)
+                rec = host_fetch(np.where(tid >= 0, tid, 0))
+                ids, dists, io, nv = _verify_fetched(
+                    qf, queries[b], top_ids, jnp.asarray(rec["vectors"]),
+                    jnp.asarray(rec["rec_labels"]),
+                    jnp.asarray(rec["rec_values"]), params, store.pages_std)
         out_ids.append(ids)
         out_d.append(dists)
-        io_pages[b] = pages + int(io)
+        with span("prefilter.readback", query=b):
+            io_pages[b] = pages + int(io)
+            n_valid[b] = int(nv)
         dist_comps[b] = cand.size
-        n_valid[b] = int(nv)
 
     return PrefilterResult(
         ids=jnp.stack(out_ids), dists=jnp.stack(out_d),

@@ -100,6 +100,7 @@ from repro.core.selectors import (InMemory, QueryFilter, is_member,
                                   kernel_view, merged_table_words)
 from repro.kernels import ops as kops
 from repro.kernels.ref import INVALID_PENALTY   # single source (1e12)
+from repro.utils.spans import span
 from repro.utils.tree import tree_put_rows, tree_take_rows
 
 BIG = jnp.float32(1e30)
@@ -400,7 +401,9 @@ def _hop_step(store, codes, mem, params, distance_fn, fetch_fn, ctx, mc,
     """Consume the in-flight record slab for one hop, merge, and select
     the next frontier. Steps keep the pre-pipelined numbering (the fetch
     that used to be step 2 now happens at the end of the previous
-    iteration — same records, same counters, earlier issue)."""
+    iteration — same records, same counters, earlier issue). Each stage
+    runs under a ``pipeann.hop.<stage>`` name scope, so device ops carry
+    their stage in the profiler's trace."""
     p = params
     l_valid = p.l_valid or p.l_search
     P, W = p.l_search, p.beam_width
@@ -421,215 +424,223 @@ def _hop_step(store, codes, mem, params, distance_fn, fetch_fn, ctx, mc,
         [jnp.ones((R,), bool), jnp.zeros((Rd,), bool)])
     hops = counters[:, 3]
 
-    # ---- 2'. the carried slab (fetched at the end of the previous
-    # iteration / by the loop prologue) ----
-    vecs = rec["vectors"].reshape(B, W, D)
-    nbrs = rec["neighbors"].reshape(B, W, R)
-    rl = rec["rec_labels"].reshape(B, W, -1)
-    rv = rec["rec_values"].reshape(B, W, -1)
-    io = counters[:, 0] + jnp.sum(cur_live, axis=1) * rec_pages
+    with jax.named_scope("pipeann.hop.fetch"):
+        # ---- 2'. the carried slab (fetched at the end of the previous
+        # iteration / by the loop prologue) ----
+        vecs = rec["vectors"].reshape(B, W, D)
+        nbrs = rec["neighbors"].reshape(B, W, R)
+        rl = rec["rec_labels"].reshape(B, W, -1)
+        rv = rec["rec_values"].reshape(B, W, -1)
+        io = counters[:, 0] + jnp.sum(cur_live, axis=1) * rec_pages
 
-    # the fused kernel computes the ADC distance itself (bitwise equal
-    # to pq.adc_lookup); a non-default distance_fn routes every slab
-    # through the caller's function instead, keeping A/B parity with
-    # the oracle — resolved statically, no cost on the default path
-    default_dist = distance_fn is pq_mod.adc_lookup
+        # the fused kernel computes the ADC distance itself (bitwise equal
+        # to pq.adc_lookup); a non-default distance_fn routes every slab
+        # through the caller's function instead, keeping A/B parity with
+        # the oracle — resolved statically, no cost on the default path
+        default_dist = distance_fn is pq_mod.adc_lookup
 
-    def slab_dist(ids_slab):
-        if default_dist:
-            return _slab_pq(codes, ids_slab, tables)
-        return jax.vmap(distance_fn)(codes[ids_slab], tables)
+        def slab_dist(ids_slab):
+            if default_dist:
+                return _slab_pq(codes, ids_slab, tables)
+            return jax.vmap(distance_fn)(codes[ids_slab], tables)
 
-    # ---- 2''. fault ladder on the slab read (core/faults.py) ----
-    # Retry → hedge → degrade. Every decision is a stateless hash of
-    # (record id, that query's own hop counter, attempt), so the
-    # bucketed compaction driver can gather rows into any order and no
-    # draw changes — pipelined stays bit-identical to single-shot under
-    # the same plan. Rows whose every attempt drew bad are "degraded".
-    plan = p.fault_plan
-    faults_c = counters[:, 4]
-    retries_c = counters[:, 5]
-    degraded_c = counters[:, 6]
-    if plan is not None and plan.reads_faulty:
-        ids_safe = jnp.where(cur_live, cur_ids, 0)
-        hcol = hops[:, None]
-        pending = (faults_mod.read_attempt_bad(ids_safe, hcol, 0, plan)
-                   & cur_live)
-        n_faults = jnp.sum(pending, axis=1)
-        n_retries = jnp.zeros_like(n_faults)
-        for a in range(1, plan.attempts):
-            n_retries = n_retries + jnp.sum(pending, axis=1)
-            pending = pending & faults_mod.read_attempt_bad(
-                ids_safe, hcol, a, plan)
-            n_faults = n_faults + jnp.sum(pending, axis=1)
-        degraded_rows = pending
-        spikes = faults_mod.read_spike(ids_safe, hcol, plan) & cur_live
-        faults_c = faults_c + n_faults + jnp.sum(spikes, axis=1)
-        retries_c = retries_c + n_retries
-        degraded_c = degraded_c + jnp.sum(degraded_rows, axis=1)
-        io = io + n_retries * rec_pages        # each retry re-reads pages
-    else:
-        degraded_rows = None
-
-    # ---- 3. re-rank + piggybacked exact verification ----
-    diff = vecs - queries[:, None, :]
-    ex_d = jnp.where(cur_live, jnp.sum(diff * diff, axis=-1), BIG)
-    ex_ok = jax.vmap(is_member)(qfilters, rl, rv) & cur_live
-    if degraded_rows is not None:
-        # a degraded row never saw its record: fall back to the
-        # in-memory tier — ADC distance and approx membership, a
-        # no-false-negative superset, so a valid result is approximated
-        # rather than dropped (verification stays post-hoc per paper)
-        deg_d = jnp.where(cur_live, slab_dist(ids_safe), BIG)
-        deg_ok = jax.vmap(is_member_approx, in_axes=(0, 0, None))(
-            qfilters, ids_safe, mem) & cur_live
-        ex_d = jnp.where(degraded_rows, deg_d, ex_d)
-        ex_ok = jnp.where(degraded_rows, deg_ok, ex_ok)
-    pos = jnp.where(active[:, None], hops[:, None] * W + w_iota, res_cap)
-    res_ids = res_ids.at[bW, pos].set(
-        jnp.where(cur_live, cur_ids, -1), mode="drop")
-    res_d = res_d.at[bW, pos].set(ex_d, mode="drop")
-    res_valid = res_valid.at[bW, pos].set(ex_ok, mode="drop")
-    # incremental early-termination bound: merge the W new verified
-    # distances into the sorted top-l_valid buffer (no res re-sort)
-    vtop = -jax.lax.top_k(
-        -jnp.concatenate([vtop, jnp.where(ex_ok, ex_d, BIG)], axis=1),
-        l_valid)[0]
-    n_okc = n_okc + jnp.sum(ex_ok, axis=1)
-
-    # ---- 4. candidate slab + visited-set dedup ----
-    if p.mode == "spec_in":
-        dn = rec["dense_neighbors"].reshape(B, W, Rd)
-        cand = jnp.concatenate([nbrs, dn], axis=2)     # (B, W, C)
-    else:
-        cand = nbrs
-    expand_live = (cur_live if degraded_rows is None
-                   else cur_live & ~degraded_rows)
-    cand = jnp.where(expand_live[:, :, None], cand, -1).reshape(B, W * C)
-    live = cand >= 0
-    safe_cand = jnp.where(live, cand, 0)
-    slots = _visited_slot(safe_cand, n_ids)
-    seen = ((jnp.take_along_axis(visited, slots >> 5, axis=1)
-             >> (slots & 31)) & 1).astype(jnp.bool_)
-    if W == 1 and "cand_first" in rec:
-        # W=1: the slab is exactly one record's candidate list, whose
-        # intra-slab duplicate structure is query-independent — read the
-        # precomputed mask off the record (records.candidate_first_mask)
-        # instead of paying the packed-sort dedup per hop. Bit-identical
-        # to _first_occurrence on the one-row slab; the first C columns of
-        # the [nbrs ++ dense] mask are the nbrs-only mask (prefix
-        # property), so post/strict slice cleanly.
-        first = rec["cand_first"].reshape(B, -1)[:, :C]
-    else:
-        first = _first_occurrence(cand, live, n_ids)
-    fresh = live & ~seen & first
-
-    # ---- 5. fused candidate pass (distance + membership + key) ----
-    if p.mode == "post":
-        ok = fresh
-        key_slab = slab_dist(safe_cand)
-        approx_c = counters[:, 2]
-    elif p.mode == "spec_in":
-        if default_dist:
-            bl_i32, bc_i32, (f_scal, f_om, f_rf, f_blo, f_bhi) = mc
-            in_merged = ((jnp.take_along_axis(merged_tbl, safe_cand >> 5,
-                                              axis=1)
-                          >> (safe_cand & 31)) & 1).astype(jnp.bool_)
-            key_slab, ok_approx = kops.hop_fused(
-                codes[safe_cand], bl_i32[safe_cand], bc_i32[safe_cand],
-                in_merged, tables, f_scal, f_om, f_rf, f_blo, f_bhi)
+        # ---- 2''. fault ladder on the slab read (core/faults.py) ----
+        # Retry → hedge → degrade. Every decision is a stateless hash of
+        # (record id, that query's own hop counter, attempt), so the
+        # bucketed compaction driver can gather rows into any order and no
+        # draw changes — pipelined stays bit-identical to single-shot under
+        # the same plan. Rows whose every attempt drew bad are "degraded".
+        plan = p.fault_plan
+        faults_c = counters[:, 4]
+        retries_c = counters[:, 5]
+        degraded_c = counters[:, 6]
+        if plan is not None and plan.reads_faulty:
+            ids_safe = jnp.where(cur_live, cur_ids, 0)
+            hcol = hops[:, None]
+            pending = (faults_mod.read_attempt_bad(ids_safe, hcol, 0, plan)
+                       & cur_live)
+            n_faults = jnp.sum(pending, axis=1)
+            n_retries = jnp.zeros_like(n_faults)
+            for a in range(1, plan.attempts):
+                n_retries = n_retries + jnp.sum(pending, axis=1)
+                pending = pending & faults_mod.read_attempt_bad(
+                    ids_safe, hcol, a, plan)
+                n_faults = n_faults + jnp.sum(pending, axis=1)
+            degraded_rows = pending
+            spikes = faults_mod.read_spike(ids_safe, hcol, plan) & cur_live
+            faults_c = faults_c + n_faults + jnp.sum(spikes, axis=1)
+            retries_c = retries_c + n_retries
+            degraded_c = degraded_c + jnp.sum(degraded_rows, axis=1)
+            io = io + n_retries * rec_pages        # each retry re-reads pages
         else:
-            ok_approx = jax.vmap(is_member_approx, in_axes=(0, 0, None))(
-                qfilters, safe_cand, mem)
-            key_slab = slab_dist(safe_cand) + jnp.where(
-                ok_approx, 0.0, INVALID_PENALTY)
-        ok = ok_approx & fresh
-        approx_c = counters[:, 2] + jnp.sum(live, axis=1)
-    else:  # strict_in: read every fresh neighbor's attrs from "SSD"
-        if getattr(fetch_fn, "wants_ctx", False):
-            # disk tier: consult the device-resident bloom/bucket words
-            # BEFORE any attribute page is read (paper's gated I/O). The
-            # gate is a no-false-negative superset, so a gated-out row's
-            # poisoned attributes (labels −1, values NaN) fail exact
-            # membership exactly where the real attributes would —
-            # bit-identical results, measurably fewer page reads
-            # (snapshot counters: gated_skips / attr_probes)
-            gate = jax.vmap(is_member_approx, in_axes=(0, 0, None))(
-                qfilters, safe_cand, mem)
-            nrec = fetch_fn(store, safe_cand.reshape(-1),
-                            need=fresh.reshape(-1),
-                            gate=gate.reshape(-1), attrs_only=True)
+            degraded_rows = None
+
+    with jax.named_scope("pipeann.hop.rerank_verify"):
+        # ---- 3. re-rank + piggybacked exact verification ----
+        diff = vecs - queries[:, None, :]
+        ex_d = jnp.where(cur_live, jnp.sum(diff * diff, axis=-1), BIG)
+        ex_ok = jax.vmap(is_member)(qfilters, rl, rv) & cur_live
+        if degraded_rows is not None:
+            # a degraded row never saw its record: fall back to the
+            # in-memory tier — ADC distance and approx membership, a
+            # no-false-negative superset, so a valid result is approximated
+            # rather than dropped (verification stays post-hoc per paper)
+            deg_d = jnp.where(cur_live, slab_dist(ids_safe), BIG)
+            deg_ok = jax.vmap(is_member_approx, in_axes=(0, 0, None))(
+                qfilters, ids_safe, mem) & cur_live
+            ex_d = jnp.where(degraded_rows, deg_d, ex_d)
+            ex_ok = jnp.where(degraded_rows, deg_ok, ex_ok)
+        pos = jnp.where(active[:, None], hops[:, None] * W + w_iota, res_cap)
+        res_ids = res_ids.at[bW, pos].set(
+            jnp.where(cur_live, cur_ids, -1), mode="drop")
+        res_d = res_d.at[bW, pos].set(ex_d, mode="drop")
+        res_valid = res_valid.at[bW, pos].set(ex_ok, mode="drop")
+        # incremental early-termination bound: merge the W new verified
+        # distances into the sorted top-l_valid buffer (no res re-sort)
+        vtop = -jax.lax.top_k(
+            -jnp.concatenate([vtop, jnp.where(ex_ok, ex_d, BIG)], axis=1),
+            l_valid)[0]
+        n_okc = n_okc + jnp.sum(ex_ok, axis=1)
+
+    with jax.named_scope("pipeann.hop.dedup"):
+        # ---- 4. candidate slab + visited-set dedup ----
+        if p.mode == "spec_in":
+            dn = rec["dense_neighbors"].reshape(B, W, Rd)
+            cand = jnp.concatenate([nbrs, dn], axis=2)     # (B, W, C)
         else:
-            nrec = fetch_fn(store, safe_cand.reshape(-1))
-        n_rl = nrec["rec_labels"].reshape(B, W * C, -1)
-        n_rv = nrec["rec_values"].reshape(B, W * C, store.n_fields)
-        ok = jax.vmap(is_member)(qfilters, n_rl, n_rv) & fresh
-        io = io + jnp.sum(fresh, axis=1)               # 1 page / neighbor
-        key_slab = slab_dist(safe_cand)
-        approx_c = counters[:, 2]
+            cand = nbrs
+        expand_live = (cur_live if degraded_rows is None
+                       else cur_live & ~degraded_rows)
+        cand = jnp.where(expand_live[:, :, None], cand, -1).reshape(B, W * C)
+        live = cand >= 0
+        safe_cand = jnp.where(live, cand, 0)
+        slots = _visited_slot(safe_cand, n_ids)
+        seen = ((jnp.take_along_axis(visited, slots >> 5, axis=1)
+                 >> (slots & 31)) & 1).astype(jnp.bool_)
+        if W == 1 and "cand_first" in rec:
+            # W=1: the slab is exactly one record's candidate list, whose
+            # intra-slab duplicate structure is query-independent — read the
+            # precomputed mask off the record (records.candidate_first_mask)
+            # instead of paying the packed-sort dedup per hop. Bit-identical
+            # to _first_occurrence on the one-row slab; the first C columns of
+            # the [nbrs ++ dense] mask are the nbrs-only mask (prefix
+            # property), so post/strict slice cleanly.
+            first = rec["cand_first"].reshape(B, -1)[:, :C]
+        else:
+            first = _first_occurrence(cand, live, n_ids)
+        fresh = live & ~seen & first
 
-    # ---- 6. slot selection: up to R approx-valid, bridge back-fill ----
-    if p.mode == "spec_in":
-        okr = ok.reshape(B, W, C)
-        fill = (fresh.reshape(B, W, C) & ~okr
-                & is_direct[None, None, :])
-        rank_ok = jnp.cumsum(okr.astype(jnp.int32), axis=2) - 1
-        rank_fill = jnp.cumsum(fill.astype(jnp.int32), axis=2) - 1
-        n_ok_row = jnp.sum(okr, axis=2, keepdims=True)
-        order_key = jnp.where(
-            okr, rank_ok.astype(jnp.float32),
-            jnp.where(fill, (n_ok_row + rank_fill).astype(jnp.float32),
-                      BIG))
-        _, take = jax.lax.top_k(-order_key, R)         # (B, W, R)
-        sel_ok = jnp.take_along_axis(okr, take, 2).reshape(B, W * R)
-        sel_fill = jnp.take_along_axis(fill, take, 2).reshape(B, W * R)
-        sel_live = sel_ok | sel_fill
-        sel_ids = jnp.take_along_axis(
-            cand.reshape(B, W, C), take, 2).reshape(B, W * R)
-        sel_key = jnp.take_along_axis(
-            key_slab.reshape(B, W, C), take, 2).reshape(B, W * R)
-        new_ids = jnp.where(sel_live, sel_ids, -1)
-        new_key = jnp.where(sel_live, sel_key, BIG)
-    else:
-        sel_live = ok
-        new_ids = jnp.where(ok, cand, -1)
-        new_key = jnp.where(ok, key_slab, BIG)
-    dist_c = counters[:, 1] + jnp.sum(sel_live, axis=1)
-    # mark *admitted* candidates visited (pool entries are marked from
-    # init, explored ones were admitted earlier): a fresh candidate
-    # that loses slot selection stays unmarked and may be re-proposed
-    # through another parent — the legacy pool/explored-membership
-    # dedup behaves the same way
-    visited = kops.or_scatter(
-        visited,
-        jnp.where(sel_live,
-                  _visited_slot(jnp.where(sel_live, new_ids, 0), n_ids),
-                  n_slots))
+    with jax.named_scope("pipeann.hop.fused_pass"):
+        # ---- 5. fused candidate pass (distance + membership + key) ----
+        if p.mode == "post":
+            ok = fresh
+            key_slab = slab_dist(safe_cand)
+            approx_c = counters[:, 2]
+        elif p.mode == "spec_in":
+            if default_dist:
+                bl_i32, bc_i32, (f_scal, f_om, f_rf, f_blo, f_bhi) = mc
+                in_merged = ((jnp.take_along_axis(merged_tbl, safe_cand >> 5,
+                                                  axis=1)
+                              >> (safe_cand & 31)) & 1).astype(jnp.bool_)
+                key_slab, ok_approx = kops.hop_fused(
+                    codes[safe_cand], bl_i32[safe_cand], bc_i32[safe_cand],
+                    in_merged, tables, f_scal, f_om, f_rf, f_blo, f_bhi)
+            else:
+                ok_approx = jax.vmap(is_member_approx, in_axes=(0, 0, None))(
+                    qfilters, safe_cand, mem)
+                key_slab = slab_dist(safe_cand) + jnp.where(
+                    ok_approx, 0.0, INVALID_PENALTY)
+            ok = ok_approx & fresh
+            approx_c = counters[:, 2] + jnp.sum(live, axis=1)
+        else:  # strict_in: read every fresh neighbor's attrs from "SSD"
+            if getattr(fetch_fn, "wants_ctx", False):
+                # disk tier: consult the device-resident bloom/bucket words
+                # BEFORE any attribute page is read (paper's gated I/O). The
+                # gate is a no-false-negative superset, so a gated-out row's
+                # poisoned attributes (labels −1, values NaN) fail exact
+                # membership exactly where the real attributes would —
+                # bit-identical results, measurably fewer page reads
+                # (snapshot counters: gated_skips / attr_probes)
+                gate = jax.vmap(is_member_approx, in_axes=(0, 0, None))(
+                    qfilters, safe_cand, mem)
+                nrec = fetch_fn(store, safe_cand.reshape(-1),
+                                need=fresh.reshape(-1),
+                                gate=gate.reshape(-1), attrs_only=True)
+            else:
+                nrec = fetch_fn(store, safe_cand.reshape(-1))
+            n_rl = nrec["rec_labels"].reshape(B, W * C, -1)
+            n_rv = nrec["rec_values"].reshape(B, W * C, store.n_fields)
+            ok = jax.vmap(is_member)(qfilters, n_rl, n_rv) & fresh
+            io = io + jnp.sum(fresh, axis=1)               # 1 page / neighbor
+            key_slab = slab_dist(safe_cand)
+            approx_c = counters[:, 2]
 
-    # ---- 7. sorted-pool merge: concatenate + one top_k ----
-    all_key = jnp.concatenate([pool_key, new_key], axis=1)
-    negm, midx = jax.lax.top_k(-all_key, P)
-    pool_key = -negm
-    pool_ids = jnp.take_along_axis(
-        jnp.concatenate([pool_ids, new_ids], axis=1), midx, 1)
-    pool_exp = jnp.take_along_axis(
-        jnp.concatenate(
-            [pool_exp, jnp.zeros(new_ids.shape, jnp.bool_)], axis=1),
-        midx, 1)
+    with jax.named_scope("pipeann.hop.slot_select"):
+        # ---- 6. slot selection: up to R approx-valid, bridge back-fill ----
+        if p.mode == "spec_in":
+            okr = ok.reshape(B, W, C)
+            fill = (fresh.reshape(B, W, C) & ~okr
+                    & is_direct[None, None, :])
+            rank_ok = jnp.cumsum(okr.astype(jnp.int32), axis=2) - 1
+            rank_fill = jnp.cumsum(fill.astype(jnp.int32), axis=2) - 1
+            n_ok_row = jnp.sum(okr, axis=2, keepdims=True)
+            order_key = jnp.where(
+                okr, rank_ok.astype(jnp.float32),
+                jnp.where(fill, (n_ok_row + rank_fill).astype(jnp.float32),
+                          BIG))
+            _, take = jax.lax.top_k(-order_key, R)         # (B, W, R)
+            sel_ok = jnp.take_along_axis(okr, take, 2).reshape(B, W * R)
+            sel_fill = jnp.take_along_axis(fill, take, 2).reshape(B, W * R)
+            sel_live = sel_ok | sel_fill
+            sel_ids = jnp.take_along_axis(
+                cand.reshape(B, W, C), take, 2).reshape(B, W * R)
+            sel_key = jnp.take_along_axis(
+                key_slab.reshape(B, W, C), take, 2).reshape(B, W * R)
+            new_ids = jnp.where(sel_live, sel_ids, -1)
+            new_key = jnp.where(sel_live, sel_key, BIG)
+        else:
+            sel_live = ok
+            new_ids = jnp.where(ok, cand, -1)
+            new_key = jnp.where(ok, key_slab, BIG)
+        dist_c = counters[:, 1] + jnp.sum(sel_live, axis=1)
+        # mark *admitted* candidates visited (pool entries are marked from
+        # init, explored ones were admitted earlier): a fresh candidate
+        # that loses slot selection stays unmarked and may be re-proposed
+        # through another parent — the legacy pool/explored-membership
+        # dedup behaves the same way
+        visited = kops.or_scatter(
+            visited,
+            jnp.where(sel_live,
+                      _visited_slot(jnp.where(sel_live, new_ids, 0), n_ids),
+                      n_slots))
 
-    # ---- 8. per-query termination ----
-    hops_new = hops + active.astype(jnp.int32)
-    frontier = jnp.any(~pool_exp & (pool_key < BIG), axis=1)
-    best_unexp = jnp.min(jnp.where(pool_exp, BIG, pool_key), axis=1)
-    settled = (n_okc >= l_valid) & (best_unexp > vtop[:, l_valid - 1])
-    active = active & (hops_new < p.max_hops) & frontier & ~settled
-    counters = jnp.stack([io, dist_c, approx_c, hops_new, faults_c,
-                          retries_c, degraded_c], axis=1)
+    with jax.named_scope("pipeann.hop.merge"):
+        # ---- 7. sorted-pool merge: concatenate + one top_k ----
+        all_key = jnp.concatenate([pool_key, new_key], axis=1)
+        negm, midx = jax.lax.top_k(-all_key, P)
+        pool_key = -negm
+        pool_ids = jnp.take_along_axis(
+            jnp.concatenate([pool_ids, new_ids], axis=1), midx, 1)
+        pool_exp = jnp.take_along_axis(
+            jnp.concatenate(
+                [pool_exp, jnp.zeros(new_ids.shape, jnp.bool_)], axis=1),
+            midx, 1)
 
-    # ---- 1'. select the NEXT frontier (its fetch is issued right after
-    # this step returns — the cross-hop prefetch) ----
-    cur_ids, cur_live, pool_exp = _select_frontier(
-        pool_ids, pool_key, pool_exp, active, W, P)
+    with jax.named_scope("pipeann.hop.terminate"):
+        # ---- 8. per-query termination ----
+        hops_new = hops + active.astype(jnp.int32)
+        frontier = jnp.any(~pool_exp & (pool_key < BIG), axis=1)
+        best_unexp = jnp.min(jnp.where(pool_exp, BIG, pool_key), axis=1)
+        settled = (n_okc >= l_valid) & (best_unexp > vtop[:, l_valid - 1])
+        active = active & (hops_new < p.max_hops) & frontier & ~settled
+        counters = jnp.stack([io, dist_c, approx_c, hops_new, faults_c,
+                              retries_c, degraded_c], axis=1)
+
+    with jax.named_scope("pipeann.hop.frontier"):
+        # ---- 1'. select the NEXT frontier (its fetch is issued right after
+        # this step returns — the cross-hop prefetch) ----
+        cur_ids, cur_live, pool_exp = _select_frontier(
+            pool_ids, pool_key, pool_exp, active, W, P)
     return HopState(pool_ids, pool_key, pool_exp, visited, res_ids, res_d,
                     res_valid, vtop, n_okc, counters, active, cur_ids,
                     cur_live)
@@ -669,14 +680,15 @@ def _hop_loop(store, codes, mem, params, distance_fn, fetch_fn, ctx, st,
     ctx_fetch = getattr(fetch_fn, "wants_ctx", False)
 
     def issue(st):
-        ids = jnp.where(st.cur_live, st.cur_ids, 0).reshape(-1)
-        if ctx_fetch:
-            return fetch_fn(store, ids,
-                            hops=jnp.repeat(st.counters[:, 3],
-                                            p.beam_width),
-                            live=st.cur_live.reshape(-1),
-                            dense=(p.mode == "spec_in"))
-        return fetch_fn(store, ids)
+        with jax.named_scope("pipeann.hop.fetch"):
+            ids = jnp.where(st.cur_live, st.cur_ids, 0).reshape(-1)
+            if ctx_fetch:
+                return fetch_fn(store, ids,
+                                hops=jnp.repeat(st.counters[:, 3],
+                                                p.beam_width),
+                                live=st.cur_live.reshape(-1),
+                                dense=(p.mode == "spec_in"))
+            return fetch_fn(store, ids)
 
     def cond(carry):
         st, _, i, g = carry
@@ -811,7 +823,6 @@ def filtered_search_pipelined(store: RecordStore, codes: jax.Array,
                               entries: jax.Array | None = None,
                               hop_chunk: int = DEFAULT_HOP_CHUNK,
                               min_bucket: int = MIN_COMPACT_BUCKET,
-                              collect_trace: bool = False,
                               async_readback: bool = True,
                               runner=None):
     """Bucketed host driver: chunked hops + straggler compaction.
@@ -838,11 +849,12 @@ def filtered_search_pipelined(store: RecordStore, codes: jax.Array,
     settled ones. ``async_readback=False`` keeps the synchronous
     reference driver (one blocking readback per chunk).
 
-    ``hop_chunk=0`` falls back to the single-shot jit. With
-    ``collect_trace=True`` returns ``(SearchResult, trace)`` where trace
-    lists ``{"hop", "active", "bucket"}`` per observed chunk boundary —
-    the benchmark's ``--active-trace`` feed (in async mode the
-    observations lag dispatch by one chunk).
+    ``hop_chunk=0`` falls back to the single-shot jit. The host stages
+    run under ``pipeann.hops.*`` spans (``utils/spans.py``): ``init``,
+    ``dispatch`` per chunk (with the observed ``active`` count, the
+    ``bucket`` width and the ``hop`` reached; in async mode the
+    observation lags dispatch by one chunk), ``readback`` of a mask,
+    ``compact`` and ``finalize``.
 
     ``runner`` (a ``distributed.ShardedSearchRunner``) swaps the hop
     kernel for its shard_map'd equivalent over the mesh-sharded record
@@ -862,11 +874,10 @@ def filtered_search_pipelined(store: RecordStore, codes: jax.Array,
             # sharded hop entry)
             hop_chunk = params.max_hops
     if hop_chunk <= 0:
-        res = filtered_search(store, codes, codebook, mem, qfilters,
-                              queries, entry, params,
-                              distance_fn=distance_fn, fetch_fn=fetch_fn,
-                              entries=entries)
-        return (res, []) if collect_trace else res
+        return filtered_search(store, codes, codebook, mem, qfilters,
+                               queries, entry, params,
+                               distance_fn=distance_fn, fetch_fn=fetch_fn,
+                               entries=entries)
     orig_b = int(queries.shape[0])
     # Quantize the top-level width to the same power-of-two bucket
     # ladder the compaction loop uses: compile keys stay bounded to the
@@ -878,89 +889,100 @@ def filtered_search_pipelined(store: RecordStore, codes: jax.Array,
     # executable per distinct composition, defeating the quantization.
     B = max(min_bucket, _pow2_at_least(orig_b))
     n_pad = B - orig_b
-    if n_pad:
-        def _pad(a):
-            a = np.asarray(a)
-            return np.concatenate(
-                [a, np.broadcast_to(a[:1], (n_pad,) + a.shape[1:])], axis=0)
-        queries = _pad(queries)
-        qfilters = jax.tree_util.tree_map(_pad, qfilters)
-        if entries is not None:
-            entries = _pad(entries)
-    full_ctx, full_st = init_search(store, codes, codebook, mem, qfilters,
-                                    queries, entry, params,
-                                    distance_fn=distance_fn,
-                                    entries=entries)
-    if n_pad:
-        full_st = full_st._replace(
-            active=full_st.active.at[orig_b:].set(False))
+    with span("hops.init", width=orig_b, bucket=B):
+        if n_pad:
+            def _pad(a):
+                a = np.asarray(a)
+                return np.concatenate(
+                    [a, np.broadcast_to(a[:1], (n_pad,) + a.shape[1:])],
+                    axis=0)
+            queries = _pad(queries)
+            qfilters = jax.tree_util.tree_map(_pad, qfilters)
+            if entries is not None:
+                entries = _pad(entries)
+        full_ctx, full_st = init_search(store, codes, codebook, mem,
+                                        qfilters, queries, entry, params,
+                                        distance_fn=distance_fn,
+                                        entries=entries)
+        if n_pad:
+            full_st = full_st._replace(
+                active=full_st.active.at[orig_b:].set(False))
     work_ctx, work_st = full_ctx, full_st
     work_map: np.ndarray | None = None   # None ⇒ identity (full width)
     work_valid: np.ndarray | None = None  # non-pad rows of the bucket
     width = B
     hops_done = 0
-    trace: list = []
 
-    def hop(ctx, st):
-        if runner is not None:
-            return runner.run(ctx, st, hop_chunk, params, distance_fn)
-        return run_hops(store, codes, mem, ctx, st, hop_chunk, params,
-                        distance_fn=distance_fn, fetch_fn=fetch_fn)
+    def hop(ctx, st, n_act):
+        with span("hops.dispatch", active=n_act, bucket=width,
+                  hop=hops_done):
+            if runner is not None:
+                st, mask = runner.run(ctx, st, hop_chunk, params,
+                                      distance_fn)
+            else:
+                st, mask = run_hops(store, codes, mem, ctx, st, hop_chunk,
+                                    params, distance_fn=distance_fn,
+                                    fetch_fn=fetch_fn)
+            if async_readback:
+                mask.copy_to_host_async()
+        return st, mask
+
+    def readback(mask):
+        with span("hops.readback"):
+            return np.asarray(mask)
 
     # act: host copy of an active mask; in async mode it may lag work_st
     # by one chunk (a superset of the truly-active rows — see docstring)
-    act = np.asarray(work_st.active)     # init-state snapshot, pre-donation
+    act = readback(work_st.active)       # init-state snapshot, pre-donation
     inflight = None                      # device mask of the newest chunk
     while True:
         n_act = int(act.sum())               # pads are inert (forced off)
-        if collect_trace:
-            trace.append({"hop": hops_done, "active": n_act,
-                          "bucket": width})
         bucket = min(B, max(min_bucket, _pow2_at_least(max(n_act, 1))))
         if n_act and bucket >= width:
             # active set still fills the current bucket: keep hopping
-            work_st, mask = hop(work_ctx, work_st)
+            work_st, mask = hop(work_ctx, work_st, n_act)
             hops_done += hop_chunk
             if not async_readback:
-                act = np.asarray(mask)
+                act = readback(mask)
                 continue
-            mask.copy_to_host_async()
             if inflight is None:
                 # prime the one-chunk pipeline: dispatch a second chunk so
                 # there is device work to hide the first mask's readback
-                work_st, inflight = hop(work_ctx, work_st)
+                work_st, inflight = hop(work_ctx, work_st, n_act)
                 hops_done += hop_chunk
-                inflight.copy_to_host_async()
-                act = np.asarray(mask)
+                act = readback(mask)
             else:
                 # read the older in-flight mask while this chunk runs
-                act, inflight = np.asarray(inflight), mask
+                act, inflight = readback(inflight), mask
             continue
         # settle or shrink: fold the working rows into the full state
         # (work_st may be one speculative chunk past the observed mask —
         # settled rows are bitwise unchanged by it)
-        if work_map is None:
-            full_st = work_st
-        else:
-            sidx = jnp.asarray(
-                np.where(work_valid, work_map, B).astype(np.int32))
-            full_st = tree_put_rows(full_st, work_st, sidx)
+        with span("hops.compact", active=n_act, bucket=bucket):
+            if work_map is None:
+                full_st = work_st
+            else:
+                sidx = jnp.asarray(
+                    np.where(work_valid, work_map, B).astype(np.int32))
+                full_st = tree_put_rows(full_st, work_st, sidx)
+            if n_act:
+                # compact the survivors into the next power-of-two
+                # bucket; the stale mask over-admits at worst (rows that
+                # settled during the speculative chunk ride along as
+                # inert valid rows)
+                surv = np.flatnonzero(act)
+                idx = (work_map[surv] if work_map is not None else surv) \
+                    .astype(np.int32)
+                pads = np.full(bucket - idx.size, idx[0], np.int32)
+                work_map = np.concatenate([idx, pads])
+                work_valid = np.arange(bucket) < idx.size
+                gidx = jnp.asarray(work_map)
+                work_ctx = tree_take_rows(full_ctx, gidx)
+                work_st = tree_take_rows(full_st, gidx)
+                work_st = work_st._replace(
+                    active=work_st.active & jnp.asarray(work_valid))
         if n_act == 0:
             break
-        # compact the survivors into the next power-of-two bucket; the
-        # stale mask over-admits at worst (rows that settled during the
-        # speculative chunk ride along as inert valid rows)
-        surv = np.flatnonzero(act)
-        idx = (work_map[surv] if work_map is not None else surv) \
-            .astype(np.int32)
-        pads = np.full(bucket - idx.size, idx[0], np.int32)
-        work_map = np.concatenate([idx, pads])
-        work_valid = np.arange(bucket) < idx.size
-        gidx = jnp.asarray(work_map)
-        work_ctx = tree_take_rows(full_ctx, gidx)
-        work_st = tree_take_rows(full_st, gidx)
-        work_st = work_st._replace(
-            active=work_st.active & jnp.asarray(work_valid))
         width = bucket
         inflight = None
         if async_readback:
@@ -970,15 +992,17 @@ def filtered_search_pipelined(store: RecordStore, codes: jax.Array,
             # that chunk an immediate-exit no-op)
             act = work_valid.copy()
             continue
-        work_st, mask = hop(work_ctx, work_st)
+        work_st, mask = hop(work_ctx, work_st, n_act)
         hops_done += hop_chunk
-        act = np.asarray(mask)
-    res = finalize_search(full_st, params)
-    if n_pad:
-        # slice on the host: a device-side slice at the raw width would
-        # compile per composition (same reason the padding is numpy)
-        res = SearchResult(*(np.asarray(a)[:orig_b] for a in res))
-    return (res, trace) if collect_trace else res
+        act = readback(mask)
+    with span("hops.finalize", width=orig_b):
+        res = finalize_search(full_st, params)
+        if n_pad:
+            # slice on the host: a device-side slice at the raw width
+            # would compile per composition (same reason the padding is
+            # numpy)
+            res = SearchResult(*(np.asarray(a)[:orig_b] for a in res))
+    return res
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,7 @@ from repro.api.types import (DeadlineExceeded, Overloaded, SearchRequest,
                              ServeError)
 from repro.core import cost_model
 from repro.core.engine import apply_rung, scan_rerank
+from repro.utils.spans import span
 
 
 def _now_us() -> float:
@@ -176,6 +177,7 @@ class SearchServer:
         self._degraded = 0
         self._warmed = False
         self._stop = False
+        self._n_batches = 0          # batches the worker has executed
         self._worker = threading.Thread(target=self._run, daemon=True,
                                         name="search-server-worker")
         self._worker.start()
@@ -300,12 +302,14 @@ class SearchServer:
     def submit(self, request: SearchRequest) -> PendingSearch:
         """Admit one request; returns its handle or raises
         ``Overloaded`` / ``DeadlineExceeded`` (shed at admission)."""
-        ci, full, scfg = self._price(request)       # host-side, lock-free
+        with span("server.price"):
+            ci, full, scfg = self._price(request)   # host-side, lock-free
         handle = PendingSearch(self.session, request)
         # the server owns scheduling: mark the handle claimed so
         # result() waits on the worker instead of forcing a session flush
         handle._claimed = True
         handle.rung = None
+        handle.queue_us = None       # admission to batch cut, stamped at cut
         now = _now_us()
         with self._work:
             if self._stop:
@@ -406,6 +410,7 @@ class SearchServer:
             budget = head
             self._queue.popleft()
             self._queued_cost -= e.cost_full
+            e.handle.queue_us = now - e.admit_us
             batch.append(e)
             batch_cost += c
         self._rung_idx = rung_idx
@@ -424,14 +429,16 @@ class SearchServer:
                         return
                     continue
                 # batching window: give the batch a chance to fill
-                while (not self._stop
-                       and len(self._queue) < cfg.max_batch):
-                    age_s = (_now_us() - self._queue[0].admit_us) / 1e6
-                    if age_s >= cfg.max_delay_s:
-                        break
-                    self._work.wait(cfg.max_delay_s - age_s)
-                batch, batch_cost, rung_idx, shed = \
-                    self._cut_batch_locked(_now_us())
+                with span("server.fill_wait"):
+                    while (not self._stop
+                           and len(self._queue) < cfg.max_batch):
+                        age_s = (_now_us() - self._queue[0].admit_us) / 1e6
+                        if age_s >= cfg.max_delay_s:
+                            break
+                        self._work.wait(cfg.max_delay_s - age_s)
+                with span("server.cut"):
+                    batch, batch_cost, rung_idx, shed = \
+                        self._cut_batch_locked(_now_us())
             for e in shed:
                 e.handle._fail(DeadlineExceeded(
                     "deadline expired while queued"))
@@ -439,7 +446,11 @@ class SearchServer:
                 self._shed += len(shed)
             if not batch:
                 continue
-            self._execute(batch, batch_cost, rung_idx)
+            number = self._n_batches
+            self._n_batches += 1
+            with span("server.execute", batch=number, size=len(batch),
+                      rung=self.ladder[rung_idx].name):
+                self._execute(batch, batch_cost, rung_idx)
 
     def _execute(self, batch: list, batch_cost: float,
                  rung_idx: int) -> None:
@@ -469,7 +480,7 @@ class SearchServer:
                         "serve batch aborted before resolving this "
                         "handle"))
         done = _now_us()
-        with self._lock:
+        with span("server.complete"), self._lock:
             self._refit_locked(batch_cost, done - t0)
             degraded = _is_degraded(rung)
             for e in batch:

@@ -192,6 +192,23 @@ def test_server_unloaded_bit_identical_to_direct(index, corpus):
         assert np.array_equal(a.dists, b.dists)
 
 
+def test_served_handles_carry_their_queue_wait(index, corpus):
+    """Every served handle is stamped, at the batch cut, with its wait
+    from admission: the batching window holds the first request longest."""
+    reqs = make_requests(corpus, n=8, seed=9)
+    with SearchServer(index, ServerConfig(max_batch=8,
+                                          max_delay_s=30.0)) as srv:
+        handles = []
+        for r in reqs:
+            handles.append(srv.submit(r))
+            time.sleep(0.002)
+        for h in handles:
+            h.result(timeout=60)
+    waits = [h.queue_us for h in handles]
+    assert all(w is not None and w >= 0 for w in waits)
+    assert waits[0] == max(waits) and waits[0] >= 8 * 2000 * 0.5
+
+
 # ---------------------------------------------------------------------------
 # Admission: backpressure + shedding
 # ---------------------------------------------------------------------------
