@@ -644,13 +644,7 @@ class FilteredANNEngine:
                 [selectors[i] for i in idxs], g.qfilter, g.queries, pp,
                 speculative=not g.strict,
                 host_fetch=ds.fetch_host if ds is not None else None)
-            with span("engine.collect", width=len(idxs)):
-                for j, i in enumerate(idxs):
-                    out_ids[i] = np.asarray(res.ids[j])
-                    out_d[i] = np.asarray(res.dists[j])
-                    stats.io_pages[i] = int(res.io_pages[j])
-                    stats.dist_comps[i] = int(res.dist_comps[j])
-                    stats.n_valid[i] = int(res.n_valid[j])
+            _collect(res, idxs, out_ids, out_d, stats)
             return
         # the bucketed pipelined driver: chunked hops + straggler
         # compaction (search.filtered_search_pipelined); hop_chunk=0 falls
@@ -663,22 +657,11 @@ class FilteredANNEngine:
                if ds is not None else
                {"runner": self._runner}
                if self._runner is not None else {}))
-        spec_in = g.params.mode == "spec_in"
-        with span("engine.collect", width=len(idxs)):
-            for j, i in enumerate(idxs):
-                out_ids[i] = np.asarray(res.ids[j])
-                out_d[i] = np.asarray(res.dists[j])
-                stats.io_pages[i] = int(res.io_pages[j]) + int(
-                    g.seed_pages[j]) + (
-                    int(plans[i].pages_prefetch) if spec_in else 0)
-                stats.dist_comps[i] = int(res.dist_comps[j])
-                stats.hops[i] = int(res.hops[j])
-                stats.fp_explored[i] = int(res.fp_explored[j])
-                stats.explored[i] = int(res.explored[j])
-                stats.n_valid[i] = int(res.n_valid[j])
-                stats.faults[i] = int(res.faults[j])
-                stats.retries[i] = int(res.retries[j])
-                stats.degraded[i] = int(res.degraded[j])
+        pages = g.seed_pages
+        if g.params.mode == "spec_in":
+            pages = pages + np.array([plans[i].pages_prefetch for i in idxs],
+                                     np.int64)
+        _collect(res, idxs, out_ids, out_d, stats, pages)
 
     def _padded(self, queries) -> np.ndarray:
         """Queries zero-padded to the store's (pq_m-aligned) dim."""
@@ -841,6 +824,35 @@ def _strict_seed_ids(sel: Selector, medoid: int,
         return np.array([medoid], np.int32), int(pages)
     take = np.linspace(0, ids.size - 1, num=min(e, ids.size)).astype(np.int64)
     return np.unique(ids[take]).astype(np.int32), int(pages)
+
+
+# QueryStats counters a group's result carries as they are (io_pages is
+# topped up with what the group charged on the host)
+_COUNTERS = ("dist_comps", "hops", "fp_explored", "explored", "n_valid",
+             "faults", "retries", "degraded")
+
+
+def _collect(res, idxs, out_ids, out_d, stats: QueryStats,
+             pages=0) -> None:
+    """Copy one group's result ``res`` out into the batch's rows ``idxs``:
+    each query's ids and distances, and its counters, plus ``pages`` on
+    ``io_pages``.
+
+    The whole result comes to the host in one ``jax.device_get``, and a
+    result already wholly on the host is not copied: a per-query,
+    per-field read would be an eager device slice and a blocking copy
+    each. ``copies`` on the span counts the copies made."""
+    copies = int(any(isinstance(a, jax.Array) for a in res))
+    with span("engine.collect", width=len(idxs), copies=copies):
+        if copies:
+            res = jax.device_get(res)
+        for i, ids, d in zip(idxs, res.ids, res.dists, strict=True):
+            out_ids[i] = ids
+            out_d[i] = d
+        stats.io_pages[idxs] = res.io_pages + pages
+        for f in _COUNTERS:
+            if f in res._fields:
+                getattr(stats, f)[idxs] = getattr(res, f)
 
 
 def brute_force_filtered(vectors: np.ndarray, rec_labels: np.ndarray,

@@ -39,9 +39,9 @@ class PrefilterParams:
 class PrefilterResult(NamedTuple):
     ids: jax.Array           # (B, k) verified-valid top-k (-1 pad)
     dists: jax.Array         # (B, k)
-    io_pages: jax.Array      # (B,) scan + re-rank pages
-    dist_comps: jax.Array    # (B,)
-    n_valid: jax.Array       # (B,)
+    io_pages: np.ndarray     # (B,) scan + re-rank pages (counted on the host)
+    dist_comps: np.ndarray   # (B,)
+    n_valid: np.ndarray      # (B,)
 
 
 @functools.partial(jax.jit, static_argnames=("l_rerank", "chunk", "distance_fn"))
@@ -209,9 +209,8 @@ def prefilter_search(store: RecordStore, codes, codebook, selectors, qfilters,
         dist_comps[b] = cand.size
 
     return PrefilterResult(
-        ids=jnp.stack(out_ids), dists=jnp.stack(out_d),
-        io_pages=jnp.asarray(io_pages), dist_comps=jnp.asarray(dist_comps),
-        n_valid=jnp.asarray(n_valid))
+        ids=jnp.stack(out_ids), dists=jnp.stack(out_d), io_pages=io_pages,
+        dist_comps=dist_comps, n_valid=n_valid)
 
 
 def _strict_scan(sel: Selector) -> tuple[np.ndarray, int]:

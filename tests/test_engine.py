@@ -146,3 +146,130 @@ def test_plan_groups_replay_execute(built, policy, mode):
             e.medoid, g.params, entries=g.entries)
         for j, i in enumerate(g.idxs):
             np.testing.assert_array_equal(np.asarray(res.ids[j]), ids[i])
+
+
+# counters each group's result carries into QueryStats (the rest stay 0)
+_COUNTERS = ("io_pages", "dist_comps", "hops", "fp_explored", "explored",
+             "n_valid", "faults", "retries", "degraded")
+
+
+def _mixed_batch(ds, e):
+    """Selectors and per-query configs for 24 queries pinned to three
+    groups: 8 in-filtered (a power-of-two width, so the pipelined search hands
+    back device arrays), 5 post-filtered (padded to 8, handed back as
+    numpy) and 11 pre-filtered (device ids and distances, host counters).
+    A 32-slot pool cap keeps each policy in one pool bucket."""
+    sels = make_selectors(ds, e, "hybrid")
+    cfg = dict(k=10, l=32, max_hops=200, max_pool=32)
+    scfgs = ([eng.SearchConfig(policy="spec_in", **cfg)] * 8
+             + [eng.SearchConfig(policy="post", **cfg)] * 5
+             + [eng.SearchConfig(policy="strict_pre", **cfg)]
+             * (len(sels) - 13))
+    return sels, scfgs
+
+
+def _replay(e, g, sels):
+    """One group of ``plan_groups`` run again through its own search path,
+    read out a row and a field at a time: (ids, dists, counters) by query."""
+    from repro.core import prefilter, search
+    if g.mech == "pre":
+        res = prefilter.prefilter_search(
+            e.store, e.codes, e.codebook, [sels[i] for i in g.idxs],
+            g.qfilter, g.queries,
+            prefilter.PrefilterParams(
+                l_rerank=g.eff_l + g.scfg.l_rerank_delta, k=g.scfg.k),
+            speculative=not g.strict)
+        pages = np.zeros(len(g.idxs), np.int64)
+    else:
+        res = search.filtered_search_pipelined(
+            e.store, e.codes, e.codebook, e.mem, g.qfilter, g.queries,
+            e.medoid, g.params, entries=g.entries, hop_chunk=g.scfg.hop_chunk)
+        cfg = e.config
+        pages = g.seed_pages + [
+            sels[i].plan(cfg.ql, cfg.cap, cfg.qr).pages_prefetch
+            if g.params.mode == "spec_in" else 0 for i in g.idxs]
+    out = {}
+    for j, i in enumerate(g.idxs):
+        counters = {f: int(getattr(res, f)[j]) if f in res._fields else 0
+                    for f in _COUNTERS}
+        counters["io_pages"] += int(pages[j])
+        out[i] = (np.asarray(res.ids[j]), np.asarray(res.dists[j]), counters)
+    return out
+
+
+def test_execute_collects_what_group_replays_give(built):
+    """execute's one-copy collection hands each query the ids, distances
+    and QueryStats a per-row replay of its group reads, bit for bit, for
+    pre-filtered groups and for graph groups whose result comes back on
+    the device (width 8) and on the host (width 5)."""
+    ds, e = built
+    sels, scfgs = _mixed_batch(ds, e)
+    ids, dists, stats = e.execute(ds.queries, sels, scfgs)
+    groups = e.plan_groups(ds.queries, sels, scfgs)
+    assert sorted((g.mech, len(g.idxs)) for g in groups) == [
+        ("in", 8), ("post", 5), ("pre", len(sels) - 13)]
+    cfg = e.config
+    for g in groups:
+        for i, (rid, rd, counters) in _replay(e, g, sels).items():
+            assert ids[i].shape == rid.shape and ids[i].dtype == rid.dtype
+            np.testing.assert_array_equal(ids[i], rid)
+            np.testing.assert_array_equal(dists[i], rd)
+            assert {f: int(getattr(stats, f)[i]) for f in _COUNTERS} == \
+                counters, (g.mech, i)
+            route = e._route(sels[i].plan(cfg.ql, cfg.cap, cfg.qr),
+                             scfgs[i])
+            assert stats.mechanism[i] == g.mech == route.mechanism
+            cost = route.costs[g.mech]
+            assert stats.est_io_pages[i] == cost.io_pages
+            assert stats.est_compute[i] == cost.compute
+
+
+def test_collect_copies_each_group_result_once(built, monkeypatch):
+    """Collecting a group indexes no device array: its result comes to the
+    host in one ``jax.device_get`` when the search left any of it on the
+    device, and in none when it is all on the host already."""
+    import jax
+    from jax._src.array import ArrayImpl
+
+    ds, e = built
+    sels, scfgs = _mixed_batch(ds, e)
+    seen = []          # per engine.collect: [width, device_gets, slices]
+    real_span, real_get = eng.span, jax.device_get
+    real_item = ArrayImpl.__getitem__
+
+    class Collect:
+        def __init__(self, width):
+            self.rec = [width, 0, 0]
+
+        def __enter__(self):
+            seen.append(self.rec)
+
+        def __exit__(self, *exc):
+            seen.append(None)
+
+    def span(name, **stats):
+        if name == "engine.collect":
+            return Collect(stats["width"])
+        return real_span(name, **stats)
+
+    def inside():
+        return seen and seen[-1] is not None
+
+    def device_get(x):
+        if inside():
+            seen[-1][1] += 1
+        return real_get(x)
+
+    def getitem(self, key):
+        if inside():
+            seen[-1][2] += 1
+        return real_item(self, key)
+
+    monkeypatch.setattr(eng, "span", span)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(ArrayImpl, "__getitem__", getitem)
+    e.execute(ds.queries, sels, scfgs)
+    collects = sorted(tuple(r) for r in seen if r is not None)
+    # the width-5 graph group came back padded and sliced on the host:
+    # nothing left to copy
+    assert collects == [(5, 0, 0), (8, 1, 0), (len(sels) - 13, 1, 0)]

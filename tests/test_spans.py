@@ -140,3 +140,19 @@ def test_no_span_outside_a_capture(index, tmp_path):
         pass
     idx.search_batch(reqs)
     assert _program_spans(tmp_path) == []
+
+
+def test_collect_counts_its_device_copies(index, tmp_path):
+    """``engine.collect`` says how many device-to-host copies it made: one
+    for the pre-filtered group (ids and distances on the device), none for
+    the three-wide graph group (padded to four and handed back on the
+    host)."""
+    idx, vectors = index
+    reqs = _requests(vectors)
+    idx.search_batch(reqs)
+    with jax.profiler.trace(str(tmp_path)):
+        idx.search_batch(reqs)
+    spans = _program_spans(tmp_path)
+    copies = {_parent(s, spans)[3]["mech"]: s[3]["copies"] for s in spans
+              if s[0] == "engine.collect"}
+    assert copies == {"pre": 1, "in": 0}
